@@ -70,19 +70,9 @@ def _ste_bwd(_, g):
 _ste.defvjp(_ste_fwd, _ste_bwd)
 
 
-def _scatter_rows(vals, idx, d: int, backend):
-    """Dense (..., d) scatter of a sparse support — backend-dispatched.
-
-    ``"pallas"`` runs the VMEM compare-and-select kernel
-    (`kernels.randtopk.ops.scatter_rows`); ``"xla"`` (and the off-TPU
-    ``"auto"`` default) is `put_along_axis`. Same dispatch contract as
-    `selection.topk_mask`.
-    """
-    if selection._resolve_backend(backend) == "pallas":
-        from repro.kernels.randtopk import ops as tk_ops
-
-        return tk_ops.scatter_rows(jnp.asarray(vals), jnp.asarray(idx), d,
-                                   interpret=selection._pallas_interpret())
+def _scatter_rows(vals, idx, d: int):
+    """Dense (..., d) scatter of a sparse support (`put_along_axis`) — the
+    XLA reference for `kernels.decode`'s sparse branches."""
     out = jnp.zeros(vals.shape[:-1] + (d,), vals.dtype)
     return jnp.put_along_axis(out, jnp.asarray(idx).astype(jnp.int32), vals,
                               axis=-1, inplace=False)
@@ -127,22 +117,20 @@ def payload_to_dense(p: Payload, shape=None, dtype=None, *, backend=None,
     if selection._resolve_backend(backend) == "pallas":
         from repro.kernels.decode import ops as dec_ops
 
-        return dec_ops.decode_rows(p, dtype=dtype, project=project,
-                                   interpret=selection._pallas_interpret())
+        return dec_ops.decode_rows(p, dtype=dtype, project=project)
     if m.kind == "dense":
         out = p.values.astype(dtype)
     elif m.kind == "slice":
         pad = [(0, 0)] * (p.values.ndim - 1) + [(0, m.d - m.k)]
         out = jnp.pad(p.values.astype(dtype), pad)
     elif m.kind == "sparse":
-        out = _scatter_rows(p.values.astype(dtype), p.indices, m.d, backend)
+        out = _scatter_rows(p.values.astype(dtype), p.indices, m.d)
     elif m.kind == "mask":
         out = mask_expand_rows(p.values.astype(dtype), p.indices, m.d)
     elif m.kind == "quant":
         out = _dequant(p).astype(dtype)
     elif m.kind == "sparse_quant":
-        out = _scatter_rows(_dequant(p).astype(dtype), p.indices, m.d,
-                            backend)
+        out = _scatter_rows(_dequant(p).astype(dtype), p.indices, m.d)
     else:
         raise ValueError(m.kind)
     if project is not None:

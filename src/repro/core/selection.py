@@ -44,9 +44,12 @@ def _resolve_backend(backend):
     return backend
 
 
-def _pallas_interpret() -> bool:
-    # interpret-mode on CPU/GPU for validation; Mosaic on a real TPU runtime
-    return jax.default_backend() != "tpu"
+def pallas_interpret(interpret=None) -> bool:
+    """A kernel's `interpret` flag: an explicit value wins; None is
+    interpret mode off a TPU (CPU validation) and Mosaic on one."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def topk_mask(x: jax.Array, k: int, *, backend: str = None) -> jax.Array:
@@ -57,7 +60,7 @@ def topk_mask(x: jax.Array, k: int, *, backend: str = None) -> jax.Array:
     if _resolve_backend(backend) == "pallas":
         from repro.kernels.randtopk import ops as tk_ops
 
-        return tk_ops.topk_mask(x, k, interpret=_pallas_interpret())
+        return tk_ops.topk_mask(x, k)
     mag = jnp.abs(x).astype(jnp.float32)
     kth = jax.lax.top_k(mag, k)[0][..., -1:]
     # Break ties deterministically: strictly-greater always in; equal-to-kth
@@ -143,8 +146,7 @@ def randtopk_mask(x: jax.Array, k: int, alpha: float, key: jax.Array,
     if _resolve_backend(backend) == "pallas":
         from repro.kernels.randtopk import ops as tk_ops
 
-        return tk_ops.randtopk_mask(x, k, alpha, key,
-                                    interpret=_pallas_interpret())
+        return tk_ops.randtopk_mask(x, k, alpha, key)
     kb, kg = jax.random.split(key)
     is_top = topk_mask(x, k, backend="xla")
     m = binomial_nontop_count(kb, alpha, k, d, x.shape[:-1])

@@ -4,7 +4,7 @@ These are the `backend="pallas"` implementations behind
 `core.compressors.payload_to_dense` (every payload kind, optional fused
 cut-projection) and `split.protocol.server_decode_to_slots` (the serving
 arena's decode->xbuf seam). Interpret mode off-TPU, Mosaic on a TPU
-runtime — the same dispatch contract as `core.selection`.
+runtime — `interpret=None` resolves per `core.selection.pallas_interpret`.
 """
 from __future__ import annotations
 
@@ -24,7 +24,7 @@ def _wire_leaves(p: Payload):
 
 
 def decode_rows(p: Payload, *, dtype=None, project=None,
-                interpret: bool = True):
+                interpret=None):
     """Fused dequant+scatter decode of any payload to dense (..., d) rows;
     with `project` ((d, p) matrix) the cut-projection epilogue runs inside
     the same kernel and (..., p) comes back instead."""
@@ -35,7 +35,7 @@ def decode_rows(p: Payload, *, dtype=None, project=None,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def decode_rows_to_slots(xbuf, p: Payload, slots, *, interpret: bool = True):
+def decode_rows_to_slots(xbuf, p: Payload, slots, *, interpret=None):
     """Decode a stacked flush payload straight into `xbuf[slots]`.
 
     xbuf is ALIASED through the kernel (`input_output_aliases`): treat the

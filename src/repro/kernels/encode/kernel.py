@@ -13,8 +13,8 @@ The encode-side mirror of `kernels.decode`. Two kernel families:
     ints at `width` bits each becomes little-endian u32 words, bit j of the
     stream landing at bit j%32 of word j//32 — the exact bitstream
     `core.wire._pack_bits` produces on host (its two-aligned-word scheme at
-    32-bit granularity: 32 values span exactly `width` words, and a static
-    loop over the 32 lanes ORs each value into its at-most-two words).
+    32-bit granularity: 32 values span exactly `width` words, each word a
+    masked lane reduction over the values that land in it).
 
 Neither family touches `jnp.dot`, so the compiled encode programs cost
 zero dot-flops — `roofline.analysis.serving_encode_costs` budgets them as
@@ -28,12 +28,14 @@ convention pinned by tests/test_encode_kernels.py.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.decode.kernel import _cumsum_lanes
+from repro.core.selection import pallas_interpret
+from repro.kernels.decode.kernel import _cumsum_lanes, _rows_blocks
 
 #: wire leaves each payload kind's encode kernel emits, in
 #: `payload.WIRE_FIELDS` order (dtypes are the kernel-friendly wide forms;
@@ -51,10 +53,14 @@ KIND_OUTPUTS = {
 def _gather_block(x, mask, k: int):
     """Compact the masked lanes of a (br, d) tile into (br, k) values +
     (br, k) int32 indices, ascending-index order — the transpose of
-    `kernels.decode._scatter_block` (compare-and-select, no gather op)."""
+    `kernels.decode._scatter_block` (compare-and-select, no gather op; the
+    j-th column is written by a select, not a dynamic lane update, which
+    Mosaic does not lower)."""
     d = x.shape[-1]
     lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape[:-1] + (d,),
                                      x.ndim - 1)
+    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape[:-1] + (k,),
+                                    x.ndim - 1)
     pos = _cumsum_lanes(mask.astype(jnp.int32)) - 1
     hit = mask & (pos < k)
 
@@ -63,8 +69,8 @@ def _gather_block(x, mask, k: int):
         sel = hit & (pos == j)
         vj = jnp.sum(jnp.where(sel, x, 0.0), axis=-1, keepdims=True)
         ij = jnp.sum(jnp.where(sel, lanes, 0), axis=-1, keepdims=True)
-        vals = jax.lax.dynamic_update_slice_in_dim(vals, vj, j, axis=-1)
-        idx = jax.lax.dynamic_update_slice_in_dim(idx, ij, j, axis=-1)
+        vals = jnp.where(cols == j, vj, vals)
+        idx = jnp.where(cols == j, ij, idx)
         return vals, idx
 
     init = (jnp.zeros(x.shape[:-1] + (k,), jnp.float32),
@@ -73,20 +79,22 @@ def _gather_block(x, mask, k: int):
 
 
 def _mask_words_block(mask, d: int):
-    """Pack a (br, d) boolean tile into (br, ceil(d/32)) u32 words — the
-    `mask` payload's device row layout (bit l%32 of word l//32)."""
+    """Pack a (br, d) boolean tile into (br, ceil(d/32)) int32 words — the
+    bit pattern of the `mask` payload's u32 device row layout (bit l%32 of
+    word l//32). Set bits are disjoint, so the int32 sum is the bitwise OR
+    (bit 31 wraps to the sign bit, the same 32 bits)."""
     nw = (d + 31) // 32
-    m = mask.astype(jnp.uint32)
+    m = mask.astype(jnp.int32)
     pad = nw * 32 - d
     if pad:
         m = jnp.concatenate(
-            [m, jnp.zeros(m.shape[:-1] + (pad,), jnp.uint32)], axis=-1)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
+            [m, jnp.zeros(m.shape[:-1] + (pad,), jnp.int32)], axis=-1)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, m.shape[:-1] + (32,),
+                                      m.ndim - 1)
     cols = []
     for j in range(nw):
         seg = m[..., 32 * j: 32 * (j + 1)]
-        cols.append(jnp.sum(seg << shifts, axis=-1, keepdims=True,
-                            dtype=jnp.uint32))
+        cols.append(jnp.sum(seg << shifts, axis=-1, keepdims=True))
     return jnp.concatenate(cols, axis=-1)
 
 
@@ -133,15 +141,6 @@ def _encode_block(kind: str, x, mask, d: int, k: int, bits: int):
     raise ValueError(kind)
 
 
-def _rows_blocks(leading_shape, block_rows: int):
-    rows = 1
-    for s in leading_shape:
-        rows *= s
-    br = min(block_rows, rows)
-    pad = (-rows) % br
-    return rows, br, pad
-
-
 def _out_descr(kind: str, d: int, k: int):
     """(width, dtype) per output leaf of `_encode_block`, in order."""
     nw = (d + 31) // 32
@@ -151,15 +150,14 @@ def _out_descr(kind: str, d: int, k: int):
         "sparse": ((k, jnp.float32), (k, jnp.int32)),
         "quant": ((d, jnp.int32), (2, jnp.float32)),
         "sparse_quant": ((k, jnp.int32), (k, jnp.int32), (2, jnp.float32)),
-        "mask": ((k, jnp.float32), (nw, jnp.uint32)),
+        "mask": ((k, jnp.float32), (nw, jnp.int32)),
     }[kind]
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "k", "bits",
-                                             "block_rows", "interpret"))
+                                             "interpret"))
 def encode_rows_kernel(x, mask=None, *, kind: str, k: int = 0,
-                       bits: int = 0, block_rows: int = 128,
-                       interpret: bool = True):
+                       bits: int = 0, interpret: Optional[bool] = None):
     """Fused one-pass encode: activation rows -> wire-leaf arrays.
 
     x    : (..., d) activation
@@ -171,9 +169,8 @@ def encode_rows_kernel(x, mask=None, *, kind: str, k: int = 0,
     leading shape `x.shape[:-1]`.
     """
     d = x.shape[-1]
-    assert d <= 16384, "dense row must fit a VMEM row tile"
     lead = x.shape[:-1]
-    rows, br, pad = _rows_blocks(lead, block_rows)
+    rows, br, pad = _rows_blocks(lead, d)
     flat = [x.reshape((rows, d))]
     needs_mask = kind in ("sparse", "sparse_quant", "mask")
     if needs_mask:
@@ -204,37 +201,43 @@ def encode_rows_kernel(x, mask=None, *, kind: str, k: int = 0,
                    for w, _ in descr],
         out_shape=[jax.ShapeDtypeStruct((flat[0].shape[0], w), dt)
                    for w, dt in descr],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(*flat)
     outs = [o[:rows].reshape(lead + (o.shape[-1],)) if pad
             else o.reshape(lead + (o.shape[-1],)) for o in outs]
     return tuple(outs)
 
 
-def _pack_block(lanes, width: int):
-    """(bg, 32) value tile -> (bg, width) u32 words: a static loop over the
-    32 lanes ORs each value's low/high parts into its aligned word(s) —
-    `core.wire._pack_bits`'s scheme at 32-bit granularity."""
-    v = lanes.astype(jnp.uint32)
+def _pack_block(v, width: int):
+    """(bg, 32) int32 value tile -> (bg, width) int32 words (the u32 bit
+    patterns). Value i of a group lands at bit (i * width) % 32 of word
+    (i * width) // 32, spilling its high bits into the next word —
+    `core.wire._pack_bits`'s scheme at 32-bit granularity. Each word is a
+    masked lane reduction over the group's shifted values (their bits are
+    disjoint, so the sum is the OR), written into its column by a select.
+    Do not replace it with per-lane slices ORed into words and
+    concatenated: that form is exact in interpret mode but drops bits
+    under Mosaic on a v5e."""
     if width < 32:
-        v = v & jnp.uint32((1 << width) - 1)
-    cols = [jnp.zeros(v.shape[:-1] + (1,), jnp.uint32)
-            for _ in range(width)]
-    for i in range(32):
-        start = i * width
-        j, off = start // 32, start % 32
-        vi = v[..., i:i + 1]
-        cols[j] = cols[j] | (vi << jnp.uint32(off))
-        if off and off + width > 32:
-            # spill into the next word; j+1 < width whenever a lane spills
-            cols[j + 1] = cols[j + 1] | (vi >> jnp.uint32(32 - off))
-    return jnp.concatenate(cols, axis=-1)
+        v = v & ((1 << width) - 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    word, off = (lane * width) // 32, (lane * width) % 32
+    low = jax.lax.shift_left(v, off)
+    spill = (off > 0) & (off + width > 32)
+    high = jax.lax.shift_right_logical(v, jnp.where(spill, 32 - off, 0))
+    cols = jax.lax.broadcasted_iota(jnp.int32, v.shape[:-1] + (width,),
+                                    v.ndim - 1)
+    out = jnp.zeros(cols.shape, jnp.int32)
+    for j in range(width):
+        part = (jnp.where(word == j, low, 0)
+                | jnp.where(spill & (word == j - 1), high, 0))
+        out = jnp.where(cols == j, jnp.sum(part, axis=-1, keepdims=True),
+                        out)
+    return out
 
 
-@functools.partial(jax.jit, static_argnames=("width", "block_groups",
-                                             "interpret"))
-def pack_bits_kernel(vals, width: int, *, block_groups: int = 256,
-                     interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("width", "interpret"))
+def pack_bits_kernel(vals, width: int, *, interpret: Optional[bool] = None):
     """Device bit-pack: flat unsigned ints -> little-endian u32 words.
 
     The returned (ceil(n/32) * width,) u32 buffer's first
@@ -246,10 +249,10 @@ def pack_bits_kernel(vals, width: int, *, block_groups: int = 256,
     vals = vals.reshape(-1)
     n = vals.shape[0]
     groups = (n + 31) // 32
-    bg = min(block_groups, groups)
+    bg = min(256, groups)
     gpad = (-groups) % bg
     v = jnp.pad(vals.astype(jnp.uint32), (0, (groups + gpad) * 32 - n))
-    v = v.reshape(groups + gpad, 32)
+    v = jax.lax.bitcast_convert_type(v.reshape(groups + gpad, 32), jnp.int32)
 
     def kernel(v_ref, o_ref):
         o_ref[...] = _pack_block(v_ref[...], width)
@@ -259,7 +262,8 @@ def pack_bits_kernel(vals, width: int, *, block_groups: int = 256,
         grid=((groups + gpad) // bg,),
         in_specs=[pl.BlockSpec((bg, 32), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bg, width), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((groups + gpad, width), jnp.uint32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((groups + gpad, width), jnp.int32),
+        interpret=pallas_interpret(interpret),
     )(v)
-    return out[:groups].reshape(groups * width)
+    return jax.lax.bitcast_convert_type(out[:groups],
+                                        jnp.uint32).reshape(groups * width)

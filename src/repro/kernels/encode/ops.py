@@ -21,7 +21,7 @@ wire:
 """
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import lru_cache
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +44,7 @@ _WIRE_DTYPES = {
 
 
 def encode_rows(x, kind: str, *, k: int = 0, bits: int = 0, mask=None,
-                interpret: bool = True) -> Payload:
+                interpret=None) -> Payload:
     """Fused one-pass encode of activation rows to a wire-dtype Payload.
 
     `mask` is the (..., d) selection mask (from `core.selection`'s
@@ -54,7 +54,10 @@ def encode_rows(x, kind: str, *, k: int = 0, bits: int = 0, mask=None,
     d = x.shape[-1]
     outs = kernel.encode_rows_kernel(x, mask, kind=kind, k=k, bits=bits,
                                      interpret=interpret)
-    outs = tuple(o.astype(dt) for o, dt in zip(outs, _WIRE_DTYPES[kind]))
+    # int32 kernel words carry the u32 mask bits: reinterpret, never convert
+    outs = tuple(jax.lax.bitcast_convert_type(o, dt) if dt == jnp.uint32
+                 else o.astype(dt)
+                 for o, dt in zip(outs, _WIRE_DTYPES[kind]))
     meta = PayloadMeta(kind, d=d, k=k if kind != "quant" else 0,
                        bits=bits if kind in ("quant", "sparse_quant")
                        else 0)
@@ -88,8 +91,7 @@ def pack_bits(vals, width: int, *, backend=None):
     from repro.core import selection
 
     if selection._resolve_backend(backend) == "pallas":
-        return kernel.pack_bits_kernel(
-            vals, width, interpret=selection._pallas_interpret())
+        return kernel.pack_bits_kernel(vals, width)
     return _pack_words_xla(vals, width)
 
 
@@ -180,9 +182,3 @@ def sections_to_bytes(meta: PayloadMeta, batch_shape, sections) -> bytes:
         else:
             parts.append(a.tobytes()[:nb])
     return b"".join(parts)
-
-
-@partial(jax.jit, static_argnames=("kind", "k", "bits", "interpret"))
-def _encode_rows_jit(x, mask, *, kind, k, bits, interpret):
-    return encode_rows(x, kind, k=k, bits=bits, mask=mask,
-                       interpret=interpret)

@@ -23,15 +23,21 @@ exactly `target` elements even under ties or unconverged bisection.
 Layout: rows tiled over the grid, the feature axis lives in VMEM whole
 (d <= 16k floats per row = 64 KiB). Outputs: bool mask (rows, d) and (for
 the deterministic kernel) the threshold (rows,) — the wire payload
-(values, indices) is extracted by the caller where needed.
+(values, indices) is extracted by the caller where needed. The in-kernel
+band rank is the log-step lane prefix sum `kernels.decode._cumsum_lanes`
+(Mosaic has no cumsum lowering).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.core.selection import pallas_interpret
+from repro.kernels.decode.kernel import _cumsum_lanes, _rows_blocks
 
 N_ITERS = 32
 _BIG = 1e30  # finite +/- sentinel; keeps bisection arithmetic NaN-free
@@ -64,9 +70,9 @@ def _count_select(scores, pool, target):
     gt = s >= hi
     band = (s >= lo) & ~gt
     need = target - jnp.sum(gt.astype(jnp.int32), axis=-1, keepdims=True)
-    band_rank = jnp.cumsum(band.astype(jnp.int32), axis=-1)
+    band_rank = _cumsum_lanes(band.astype(jnp.int32))
     sel = gt | (band & (band_rank <= need))
-    return jnp.where(target > 0, sel, jnp.zeros_like(sel)), lo
+    return sel & (target > 0), lo
 
 
 def _topk_mask_kernel(x_ref, mask_ref, thr_ref, *, k: int):
@@ -74,8 +80,8 @@ def _topk_mask_kernel(x_ref, mask_ref, thr_ref, *, k: int):
     mag = jnp.abs(x.astype(jnp.float32))
     target = jnp.full(mag.shape[:-1] + (1,), k, jnp.int32)
     mask, thr = _count_select(mag, jnp.ones_like(mag, dtype=bool), target)
-    mask_ref[...] = mask
-    thr_ref[...] = thr[..., 0]
+    mask_ref[...] = mask.astype(jnp.int32)
+    thr_ref[...] = thr
 
 
 def _randtopk_mask_kernel(x_ref, g_ref, m_ref, mask_ref, *, k: int):
@@ -89,78 +95,42 @@ def _randtopk_mask_kernel(x_ref, g_ref, m_ref, mask_ref, *, k: int):
     is_top, _ = _count_select(mag, jnp.ones_like(mag, dtype=bool), k_arr)
     sel_top, _ = _count_select(g, is_top, k_arr - m)
     sel_non, _ = _count_select(g, ~is_top, m)
-    mask_ref[...] = sel_top | sel_non
+    mask_ref[...] = (sel_top | sel_non).astype(jnp.int32)
 
 
-def _scatter_rows_kernel(v_ref, i_ref, o_ref, *, k: int):
-    """Per-row sparse scatter: o[r, i[r, j]] = v[r, j] for j < k.
-
-    The decode-side counterpart of the selection kernels: (values, indices)
-    off the wire become the dense cut view without ever leaving the device.
-    No gather/scatter unit is used — each of the k support elements is
-    placed by one branch-free lane-parallel compare-and-select over the
-    VMEM-resident row tile, accumulated in f32 (O(k d) elementwise work,
-    same layout-friendliness as the bisection kernels above). Support
-    indices are unique per row by construction (a top-k support); duplicate
-    indices would *sum* here where XLA's put_along_axis keeps one write.
-    """
-    v = v_ref[...].astype(jnp.float32)                 # (br, k)
-    idx = i_ref[...].astype(jnp.int32)                 # (br, k)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 1)
-
-    def body(j, acc):
-        ij = jax.lax.dynamic_slice_in_dim(idx, j, 1, axis=1)   # (br, 1)
-        vj = jax.lax.dynamic_slice_in_dim(v, j, 1, axis=1)
-        return acc + jnp.where(lanes == ij, vj, 0.0)
-
-    o_ref[...] = jax.lax.fori_loop(
-        0, k, body, jnp.zeros(o_ref.shape, jnp.float32))
-
-
-def _rows_blocks(x, block_rows: int):
-    orig_shape = x.shape
-    d = orig_shape[-1]
-    assert d <= 16384, "feature axis must fit a VMEM row tile"
-    rows = 1
-    for s in orig_shape[:-1]:
-        rows *= s
-    br = min(block_rows, rows)
-    pad = (-rows) % br
-    return orig_shape, d, rows, br, pad
-
-
-@functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
-def topk_mask_threshold(x, k: int, *, block_rows: int = 128,
-                        interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def topk_mask_threshold(x, k: int, *, interpret: Optional[bool] = None):
     """x: (..., d) -> (mask bool (..., d), thr f32 (...,)).
 
-    interpret=True executes the kernel body on CPU for validation; on a TPU
-    runtime pass interpret=False to emit the Mosaic kernel.
+    `interpret` defaults to interpret mode off a TPU (CPU validation) and
+    to the Mosaic kernel on one (`core.selection.pallas_interpret`).
     """
-    orig_shape, d, rows, br, pad = _rows_blocks(x, block_rows)
+    orig_shape, d = x.shape, x.shape[-1]
+    rows, br, pad = _rows_blocks(orig_shape[:-1], d)
     x2 = x.reshape(rows, d)
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
     grid = (x2.shape[0] // br,)
 
+    # int32 mask and (rows, 1) threshold: Mosaic stores neither i1 tiles
+    # nor 1-D row blocks
     mask, thr = pl.pallas_call(
         functools.partial(_topk_mask_kernel, k=k),
         grid=grid,
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
-                   pl.BlockSpec((br,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((x2.shape[0], d), jnp.bool_),
-                   jax.ShapeDtypeStruct((x2.shape[0],), jnp.float32)],
-        interpret=interpret,
+                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((x2.shape[0], d), jnp.int32),
+                   jax.ShapeDtypeStruct((x2.shape[0], 1), jnp.float32)],
+        interpret=pallas_interpret(interpret),
     )(x2)
-    if pad:
-        mask, thr = mask[:rows], thr[:rows]
+    mask, thr = mask[:rows] != 0, thr[:rows, 0]
     return mask.reshape(orig_shape), thr.reshape(orig_shape[:-1])
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
-def randtopk_mask_kernel(x, gumbel, m, k: int, *, block_rows: int = 128,
-                         interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def randtopk_mask_kernel(x, gumbel, m, k: int, *,
+                         interpret: Optional[bool] = None):
     """Eq. (7) randomized-selection mask, fused in one Pallas kernel.
 
     x      : (..., d) activations
@@ -169,7 +139,8 @@ def randtopk_mask_kernel(x, gumbel, m, k: int, *, block_rows: int = 128,
              [0, min(k, d - k)] (see selection.binomial_nontop_count)
     Returns a bool mask with exactly k selected per row.
     """
-    orig_shape, d, rows, br, pad = _rows_blocks(x, block_rows)
+    orig_shape, d = x.shape, x.shape[-1]
+    rows, br, pad = _rows_blocks(orig_shape[:-1], d)
     x2 = x.reshape(rows, d)
     g2 = gumbel.reshape(rows, d).astype(jnp.float32)
     m2 = m.reshape(rows, 1).astype(jnp.int32)
@@ -186,45 +157,7 @@ def randtopk_mask_kernel(x, gumbel, m, k: int, *, block_rows: int = 128,
                   pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((x2.shape[0], d), jnp.bool_),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((x2.shape[0], d), jnp.int32),
+        interpret=pallas_interpret(interpret),
     )(x2, g2, m2)
-    if pad:
-        mask = mask[:rows]
-    return mask.reshape(orig_shape)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("d", "block_rows", "interpret"))
-def scatter_rows_kernel(values, indices, d: int, *, block_rows: int = 128,
-                        interpret: bool = True):
-    """Sparse wire payload -> dense rows, fused on device.
-
-    values  : (..., k) selected values (any float dtype; accumulated f32)
-    indices : (..., k) support indices (uint16/int32)
-    Returns the dense (..., d) scatter with values.dtype, zeros elsewhere.
-    This is the `backend="pallas"` implementation behind the sparse branch
-    of `core.compressors.payload_to_dense` — the decode half that
-    `runtime.server` runs per flush straight into the slot arena.
-    """
-    orig_shape, k, rows, br, pad = _rows_blocks(values, block_rows)
-    assert d <= 16384, "dense row must fit a VMEM row tile"
-    v2 = values.reshape(rows, k)
-    i2 = indices.reshape(rows, k).astype(jnp.int32)
-    if pad:
-        v2 = jnp.pad(v2, ((0, pad), (0, 0)))
-        i2 = jnp.pad(i2, ((0, pad), (0, 0)))
-    grid = (v2.shape[0] // br,)
-
-    dense = pl.pallas_call(
-        functools.partial(_scatter_rows_kernel, k=k),
-        grid=grid,
-        in_specs=[pl.BlockSpec((br, k), lambda i: (i, 0)),
-                  pl.BlockSpec((br, k), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((v2.shape[0], d), jnp.float32),
-        interpret=interpret,
-    )(v2, i2)
-    if pad:
-        dense = dense[:rows]
-    return dense.reshape(orig_shape[:-1] + (d,)).astype(values.dtype)
+    return (mask[:rows] != 0).reshape(orig_shape)

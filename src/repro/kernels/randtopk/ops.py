@@ -2,10 +2,11 @@
 
 These are the `backend="pallas"` implementations behind
 `core.selection.topk_mask` / `randtopk_mask` (interpret mode off-TPU,
-Mosaic on a TPU runtime). The deterministic support and the Eq. (7)
-randomization (Binomial pool split + Gumbel race) both run in-kernel; only
-the PRNG draws (Gumbel noise, Binomial counts) are generated outside with
-`jax.random` and streamed in as kernel operands.
+Mosaic on a TPU runtime, per `core.selection.pallas_interpret`). The
+deterministic support and the Eq. (7) randomization (Binomial pool split +
+Gumbel race) both run in-kernel; only the PRNG draws (Gumbel noise,
+Binomial counts) are generated outside with `jax.random` and streamed in
+as kernel operands.
 """
 from __future__ import annotations
 
@@ -18,28 +19,15 @@ from repro.kernels.randtopk import kernel
 
 
 @partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_mask(x, k: int, *, interpret: bool = True):
+def topk_mask(x, k: int, *, interpret=None):
     if k >= x.shape[-1]:
         return jnp.ones_like(x, dtype=bool)
     mask, _ = kernel.topk_mask_threshold(x, k, interpret=interpret)
     return mask
 
 
-@partial(jax.jit, static_argnames=("d", "interpret"))
-def scatter_rows(values, indices, d: int, *, interpret: bool = True):
-    """Dense (..., d) rows from a sparse (values, indices) wire payload.
-
-    The decode-side kernel: what `sparse_to_dense`/`put_along_axis` does on
-    the host happens in VMEM instead, so a compressed payload is densified
-    only on device (the serving arena's `decode_to_slots` path). Support
-    indices must be unique per row (any top-k support is); duplicates sum.
-    """
-    return kernel.scatter_rows_kernel(values, indices, d,
-                                      interpret=interpret)
-
-
 @partial(jax.jit, static_argnames=("k", "alpha", "interpret"))
-def randtopk_mask(x, k: int, alpha: float, key, *, interpret: bool = True):
+def randtopk_mask(x, k: int, alpha: float, key, *, interpret=None):
     """Kernel-backed Eq. (7) selection mask (fused top-k + Gumbel race)."""
     from repro.core import selection
 

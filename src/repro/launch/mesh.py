@@ -6,13 +6,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` across jax versions: newer releases want explicit
-    Auto axis_types; 0.4.x predates the argument (everything is Auto)."""
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=axis_types)
+    """`jax.make_mesh` with every axis Auto-sharded."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,6 +27,7 @@ import argparse
 import numpy as np
 
 import repro.configs as configs
+from repro.launch import compile_cache
 from repro.models.config import SplitConfig
 from repro.obs.export import write_trace
 from repro.obs.trace import Tracer
@@ -123,6 +124,7 @@ def main(argv=None):
     lgrp.add_argument("--bandwidth", type=float, default=400_000.0,
                       help="per-client link bytes/s (0 = infinite)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = configs.get(args.arch, smoke=args.smoke)
     if args.split:

@@ -16,6 +16,7 @@ import jax
 import repro.configs as configs
 from repro.checkpoint import latest_step, restore, save
 from repro.data.pipeline import TokenPipeline
+from repro.launch import compile_cache
 from repro.launch.steps import make_eval_step, make_train_step
 from repro.models import transformer
 from repro.models.common import count_params
@@ -49,6 +50,7 @@ def main(argv=None, *, clock: Clock = SYSTEM_CLOCK):
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = configs.get(args.arch, smoke=args.smoke)
     if args.split:

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models import common
 from repro.models.config import ArchConfig, Runtime
 
@@ -152,7 +151,7 @@ def moe(p, cfg: ArchConfig, rt: Runtime, x):
 
         out_bspec = (P(batch_axes if batch_axes else None, "model", None)
                      if scatter_seq else bspec)
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             ranked, mesh=mesh,
             in_specs=(bspec, P(None, None), P("model", "data", None),
                       P("model", "data", None), P("model", None, "data")),

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.config import Runtime
 
 
@@ -49,8 +48,8 @@ def gather_seq(y, rt: Runtime):
     in_spec = P(batch_axes if batch_axes else None, "model", None)
     out_spec = P(batch_axes if batch_axes else None, None, None)
 
-    return shard_map(gather_seq_local, mesh=mesh, in_specs=(in_spec,),
-                     out_specs=out_spec, check_vma=False)(y)
+    return jax.shard_map(gather_seq_local, mesh=mesh, in_specs=(in_spec,),
+                         out_specs=out_spec, check_vma=False)(y)
 
 
 def out_proj_rs(h, w, rt: Runtime, *, w_spec=P("model", "data")):
@@ -75,8 +74,8 @@ def out_proj_rs(h, w, rt: Runtime, *, w_spec=P("model", "data")):
     def f(h_l, w_l):
         return out_proj_rs_local(h_l, w_l, w_spec=w_spec)
 
-    return shard_map(f, mesh=mesh, in_specs=(h_spec, w_spec),
-                     out_specs=o_spec)(h, w)
+    return jax.shard_map(f, mesh=mesh, in_specs=(h_spec, w_spec),
+                         out_specs=o_spec)(h, w)
 
 
 def out_proj_rs_local(h_l, w_l, *, w_spec=P("model", "data"),

@@ -36,6 +36,10 @@ from repro.split import protocol
 #: killing the process. `clear_serving_steps()` is the shutdown hook.
 _STEP_CACHE: dict = {}
 
+#: overall wall-clock bound on one `run_streaming` session fleet after warm
+#: (clients + serve loop together); a run past it raises TimeoutError
+_RUN_DEADLINE_S = 900.0
+
 
 def _serving_steps(cfg: ArchConfig, rt: Runtime, cut: int, dtype_name: str,
                    backend: Optional[str], mesh=None):
@@ -53,7 +57,7 @@ def _serving_steps(cfg: ArchConfig, rt: Runtime, cut: int, dtype_name: str,
     if pair is None:
         top = steps.make_arena_top_step(cfg, rt, cut, mesh=mesh)
         pair = _STEP_CACHE[key] = jit_serving_steps(
-            top, dtype=jnp.dtype(dtype_name), backend=backend)
+            top, dtype=jnp.dtype(dtype_name), backend=backend, mesh=mesh)
     return pair
 
 
@@ -158,7 +162,16 @@ def run_streaming(cfg: ArchConfig, *, n_clients: int = 8, prompt_len: int = 4,
     # pair is shared across runs (see _serving_steps)
     tracer = tracer if tracer is not None else NULL_TRACER
     registry = MetricsRegistry()        # per-run, isolated
-    server = StreamingServer(params, None, make_top_cache,
+    # the label owner's copy of the weights: replicated over its mesh once,
+    # not re-broadcast by every flush; the feature owners keep theirs on
+    # their own (default) device
+    top_params = params
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        top_params = jax.device_put(params,
+                                    NamedSharding(mesh, PartitionSpec()))
+    server = StreamingServer(top_params, None, make_top_cache,
                              max_batch=max_batch,
                              max_wait=max_wait, dtype=cfg.adtype(),
                              capacity=capacity or n_clients,
@@ -205,17 +218,26 @@ def run_streaming(cfg: ArchConfig, *, n_clients: int = 8, prompt_len: int = 4,
     threads = [threading.Thread(target=c.run, daemon=True) for c in clients]
     for t in threads:
         t.start()
+    # one overall deadline for the whole run; a server failure ends the
+    # wait at once (the failed sessions can no longer be answered)
+    deadline = t0 + _RUN_DEADLINE_S
     for t in threads:
-        t.join(timeout=120)
+        while (t.is_alive() and not server.errors
+               and time.perf_counter() < deadline):
+            t.join(timeout=0.01)
     # guaranteed stop even if a CLOSE frame was lost to injected faults
     server.shutdown()
-    serve_thread.join(timeout=60)
+    serve_thread.join(timeout=max(0.0, deadline - time.perf_counter()))
     wall = time.perf_counter() - t0
 
     if server.errors:
-        raise RuntimeError(
-            f"server reader threads failed: {server.errors}") \
-            from server.errors[0]
+        raise server.errors[0]          # the first failed flush or reader
+    alive = [c.id for c, t in zip(clients, threads) if t.is_alive()]
+    if alive or serve_thread.is_alive():
+        raise TimeoutError(
+            f"run not finished within {_RUN_DEADLINE_S:.0f}s: clients {alive} "
+            f"still running, serve loop "
+            f"{'alive' if serve_thread.is_alive() else 'done'}")
     errs = [(c.id, c.error) for c in clients if c.error is not None]
     if errs:
         raise RuntimeError(f"client sessions failed: {errs}") from errs[0][1]

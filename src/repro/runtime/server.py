@@ -68,15 +68,17 @@ _EVICTING = object()
 
 
 def jit_serving_steps(top_step: Callable, *, dtype,
-                      backend: Optional[str] = None):
+                      backend: Optional[str] = None, mesh=None):
     """The server's jitted step pair: (donated plain arena step, donated
     fused decode+step). Split out so `runtime.engine` can cache the pair
     across `run_streaming` calls — jit compile caches live on the wrapped
     callable, and rebuilding the pair per run re-pays every per-(meta,
-    bucket) compile the warm loop just amortized."""
+    bucket) compile the warm loop just amortized. `mesh` is the one
+    `top_step` was built with."""
     top = jax.jit(top_step, donate_argnums=(2,))
     fused = jax.jit(
-        steps.make_fused_decode_step(top_step, dtype=dtype, backend=backend),
+        steps.make_fused_decode_step(top_step, dtype=dtype, backend=backend,
+                                     mesh=mesh),
         donate_argnums=(1, 4))
     return top, fused
 
@@ -107,7 +109,7 @@ class FrameServerBase:
         self._slot_cv = threading.Condition(self._lock)
         self._readers: List[threading.Thread] = []
         self._open_readers = 0
-        self.errors: List[BaseException] = []   # reader-thread failures
+        self.errors: List[BaseException] = []   # reader / serve failures
         self.faults_detected = 0    # malformed frames rejected (connections
         #                             retired with an error frame, not dead)
         self.expected_sessions: int = 0     # set by the engine; the serve
@@ -341,7 +343,7 @@ class StreamingServer(FrameServerBase):
         # arena step keeps working and jits here.
         if jit_steps is None:
             jit_steps = jit_serving_steps(top_step, dtype=dtype,
-                                          backend=backend)
+                                          backend=backend, mesh=mesh)
         self.top_step, self._fused_step = jit_steps
         self.dtype = dtype
         self.backend = backend              # sparse-decode backend dispatch
@@ -531,13 +533,28 @@ class StreamingServer(FrameServerBase):
     # -- serving -------------------------------------------------------------
 
     def serve_loop(self) -> None:
-        """Flush/process until every connection has closed and drained."""
-        while True:
-            batch = self.queue.get_batch(idle_timeout=0.05)
-            if batch:
-                self._process(batch)
-            elif self.queue.drained:
-                return
+        """Flush/process until every connection has closed and drained.
+
+        A flush that raises ends the loop: the exception goes to `errors`
+        (the engine raises it), the queue closes, and every session gets
+        an error frame, so a client blocked on its reply fails now instead
+        of waiting out its reply timeout."""
+        try:
+            while True:
+                batch = self.queue.get_batch(idle_timeout=0.05)
+                if batch:
+                    self._process(batch)
+                elif self.queue.drained:
+                    return
+        except Exception as e:          # surfaced by the engine
+            with self._lock:
+                self.errors.append(e)
+                sessions = list(self.sessions.values())
+            self.queue.close()
+            msg = f"serve loop failed: {type(e).__name__}"
+            for sess in sessions:
+                sess.endpoint.send(wire.encode_error_frame(
+                    sess.id, 0, wire.ERR_PROTOCOL, msg))
 
     def warm(self, example_payloads) -> None:
         """Compile every hot-loop jit before the serving clock starts.
@@ -559,7 +576,7 @@ class StreamingServer(FrameServerBase):
                                                    slots, size)
                 self.arena.xbuf = protocol.server_decode_to_slots(
                     self.arena.xbuf, stacked, slots, dtype=self.dtype,
-                    backend=self.backend)
+                    backend=self.backend, mesh=self._mesh)
                 _, self.arena.xbuf, self.arena.cache = self._fused_step(
                     self.params, self.arena.xbuf, stacked, slots,
                     self.arena.cache, inactive)
@@ -640,7 +657,7 @@ class StreamingServer(FrameServerBase):
                                            self._bucket(len(group)))
         self.arena.xbuf = protocol.server_decode_to_slots(
             self.arena.xbuf, stacked, slots, dtype=self.dtype,
-            backend=self.backend)
+            backend=self.backend, mesh=self._mesh)
 
     def _process(self, items) -> None:
         # queue-wait accounting for every frame this flush picked up

@@ -121,10 +121,13 @@ def make_arena_top_step(cfg: ArchConfig, rt: Runtime, cut: int,
 
     With `mesh` (a `jax.sharding.Mesh`), the step runs under `shard_map`
     with arena rows sharded over every mesh axis and the lm head
-    vocab-parallel over 'model' — served tokens stay bit-identical to the
-    mesh-less path at any mesh shape (docs/sharding.md gives the
-    exactness argument). `mesh=None` is exactly the pre-mesh single-device
-    program.
+    vocab-parallel over 'model' (docs/sharding.md). Every split is a batch
+    or output-dim split, never a contraction split; still, a shard runs
+    the per-row program over fewer rows, and the compiler may pick another
+    dot kernel and summation order for that batch, so new cache leaves
+    agree with the mesh-less step to a few ulps, not bit for bit (tests
+    pin tokens exact and KV within a tolerance). `mesh=None` is exactly
+    the pre-mesh single-device program.
     """
 
     def one_session(params, x, cache, active):
@@ -179,7 +182,6 @@ def _make_sharded_arena_step(cfg: ArchConfig, rt: Runtime, cut: int,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.models import common, tp
 
     axes = tuple(mesh.axis_names)
@@ -248,7 +250,7 @@ def _make_sharded_arena_step(cfg: ArchConfig, rt: Runtime, cut: int,
         pspec["unembed"] = P(None, "model")
         cspec = jax.tree.map(row_spec, cache)
         x = xbuf[: active.shape[0]]
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, row_spec(x), cspec, row_spec(active)),
             out_specs=(tok_spec, cspec),
@@ -258,7 +260,7 @@ def _make_sharded_arena_step(cfg: ArchConfig, rt: Runtime, cut: int,
 
 
 def make_fused_decode_step(top_step: Callable, *, dtype,
-                           backend=None) -> Callable:
+                           backend=None, mesh=None) -> Callable:
     """Fuse the decode->step seam into ONE dispatch.
 
     (params, xbuf, payload, slots, cache, active) -> (tokens, xbuf, cache):
@@ -269,6 +271,8 @@ def make_fused_decode_step(top_step: Callable, *, dtype,
     serving loop's single-meta flushes (every pure-compressor mix) pay one
     dispatch per flush instead of decode + step; jit caches one program per
     (payload meta, flush-rows bucket).
+
+    `mesh` is the one `top_step` was built with (None: one device).
 
     `xbuf` (arg 1) and `cache` (arg 4) must be DONATED by the jitting
     caller (`runtime.server`): both alias in place on TPU, and the rebound
@@ -283,7 +287,8 @@ def make_fused_decode_step(top_step: Callable, *, dtype,
 
     def fused_step(params, xbuf, payload, slots, cache, active):
         xbuf = protocol.decode_to_slots_in_jit(
-            xbuf, payload, slots, dtype=dtype_name, backend=backend)
+            xbuf, payload, slots, dtype=dtype_name, backend=backend,
+            mesh=mesh)
         tokens, cache = top_step(params, xbuf, cache, active)
         return tokens, xbuf, cache
 

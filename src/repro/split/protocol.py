@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import compressors, selection
 from repro.core.payload import Payload, PayloadMeta
 from repro.models.config import ArchConfig, Runtime, SplitConfig
@@ -79,7 +78,7 @@ def _pod_permute(rt: Runtime, *leaves, inverse: bool = False):
     def body(*xs):
         return tuple(jax.lax.ppermute(x, "pod", perm) for x in xs)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(spec_for(a) for a in leaves),
         out_specs=tuple(spec_for(a) for a in leaves),
@@ -315,8 +314,7 @@ def client_encode_device(comp: compressors.Compressor, x, *, key=None,
         mask = (comp._mask(x, key, training)
                 if kind in ("sparse", "sparse_quant", "mask") else None)
         p = enc_ops.encode_rows(x, kind, k=k,
-                                bits=getattr(comp, "bits", 0), mask=mask,
-                                interpret=selection._pallas_interpret())
+                                bits=getattr(comp, "bits", 0), mask=mask)
     else:
         p = comp.encode(x, key=key, training=training)
     return p, enc_ops.pack_payload(p, backend=comp.backend)
@@ -357,35 +355,45 @@ def server_decode_device(p: Payload, *, dtype=None, backend=None):
     return _decode_device_jit(p, dtype=dt, backend=backend)
 
 
-def decode_to_slots_in_jit(xbuf, p: Payload, slots, *, dtype, backend):
+def decode_to_slots_in_jit(xbuf, p: Payload, slots, *, dtype, backend,
+                           mesh=None):
     """Trace-time body of the slot decode — shared by `_decode_to_slots_jit`
     and the serving runtime's fused decode+step program
     (`runtime.steps.make_fused_decode_step`), so both paths have identical
     numerics by construction. `backend="pallas"` runs the fused one-kernel
     path (dequant + scatter + slot placement in a single pass, xbuf aliased
     straight through the kernel); XLA decodes then scatters `xbuf[slots]`.
+
+    With a device `mesh` (the sharded arena) xbuf and the flush payload
+    are replicated over it, and the kernel runs once per device inside a
+    `shard_map` on the replicated blocks: a Mosaic kernel is never
+    partitioned by the compiler.
     """
     from repro.core import selection
 
     if selection._resolve_backend(backend) == "pallas":
         from repro.kernels.decode import ops as dec_ops
 
-        return dec_ops.decode_rows_to_slots(
-            xbuf, p, slots, interpret=selection._pallas_interpret())
+        if mesh is None:
+            return dec_ops.decode_rows_to_slots(xbuf, p, slots)
+        return jax.shard_map(dec_ops.decode_rows_to_slots, mesh=mesh,
+                             in_specs=P(), out_specs=P(),
+                             check_vma=False)(xbuf, p, jnp.asarray(slots))
     rows = compressors.payload_to_dense(p, dtype=jnp.dtype(dtype),
                                         backend=backend)
     return xbuf.at[slots].set(rows)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype", "backend"),
+@functools.partial(jax.jit, static_argnames=("dtype", "backend", "mesh"),
                    donate_argnums=(0,))
-def _decode_to_slots_jit(xbuf, p: Payload, slots, *, dtype: str, backend):
+def _decode_to_slots_jit(xbuf, p: Payload, slots, *, dtype: str, backend,
+                         mesh):
     return decode_to_slots_in_jit(xbuf, p, slots, dtype=dtype,
-                                  backend=backend)
+                                  backend=backend, mesh=mesh)
 
 
 def server_decode_to_slots(xbuf, p: Payload, slots, *, dtype=None,
-                           backend=None):
+                           backend=None, mesh=None):
     """Device/slot variant of `server_decode`: decode a *stacked* payload
     (leading batch axis = flush rows) and scatter the dense rows straight
     into `xbuf[slots]` — the serving arena's cut-activation buffer.
@@ -395,11 +403,12 @@ def server_decode_to_slots(xbuf, p: Payload, slots, *, dtype=None,
     staging array exists on the host at any point). `slots` maps flush row i
     -> arena slot; rows padded onto a scratch slot are how the server keeps
     one compile per payload meta. Jit caches by (meta, shapes, dtype,
-    backend).
+    backend, mesh); `mesh` is the sharded arena's, over which xbuf is
+    replicated.
     """
     dt = jnp.dtype(dtype or jnp.float32).name
     return _decode_to_slots_jit(xbuf, p, jnp.asarray(slots, jnp.int32),
-                                dtype=dt, backend=backend)
+                                dtype=dt, backend=backend, mesh=mesh)
 
 
 def server_grad_encode(p: Payload, g) -> Payload:

@@ -1,15 +1,14 @@
 """Property-based fuzz of the frame layer: chunk boundaries, garbage
 prefixes, interleaved sessions, and single-byte corruption.
 
-Built on `tests/_hypothesis_compat.py`, so the properties run (with
-fixed-seed sampled examples) even without `hypothesis` installed. The core
-contract under fuzz: a `FrameReader` either yields exactly the frames that
-were sent, or raises a typed `wire.WireError` — it never yields a frame
-that was not sent, and never hangs on a complete buffer.
+Built on `hypothesis`. The core contract under fuzz: a `FrameReader`
+either yields exactly the frames that were sent, or raises a typed
+`wire.WireError` — it never yields a frame that was not sent, and never
+hangs on a complete buffer.
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 from repro.core import compressors as C, wire

@@ -86,9 +86,17 @@ def test_wire_row_is_identity_without_pod_and_a_block_swap_with():
 @pytest.mark.slow
 def test_sharded_step_matches_unsharded_and_freezes_inactive():
     """Direct step drive on 8 forced devices: the shard_map arena step's
-    tokens AND every new-cache leaf are bit-identical to the mesh-less
-    step, at data-only, data x model, and pod meshes — and inactive rows
-    never move."""
+    tokens are bit-identical to the mesh-less step and every new-cache
+    leaf agrees within a tolerance, at data-only, data x model, and pod
+    meshes — and inactive rows never move (bit-identical).
+
+    Why a tolerance on the KV leaves: each new K/V element is a
+    d_model-term f32 dot product, and a shard runs the same per-row
+    program over fewer rows, for which XLA may pick another dot
+    kernel and summation order. That moves an element by a few ulps of
+    the accumulated magnitude (observed: 452 of 16384 elements, at most
+    1.4e-6, on XLA:CPU); 1e-5 absolute + 1e-5 relative bounds 256 f32
+    terms of unit-scale products with margin."""
     out = _run_subprocess(_PRELUDE, """
         rt = Runtime(mesh=None, training=False)
         cap = 8
@@ -119,7 +127,8 @@ def test_sharded_step_matches_unsharded_and_freezes_inactive():
                                           np.asarray(tok)[perm])
             for r, n in zip(jax.tree.leaves(ref_cache),
                             jax.tree.leaves(new)):
-                np.testing.assert_array_equal(np.asarray(r), np.asarray(n))
+                np.testing.assert_allclose(np.asarray(n), np.asarray(r),
+                                           rtol=1e-5, atol=1e-5)
             # frozen rows: bit-identical to the pre-step cache
             for o, n in zip(jax.tree.leaves(cache0), jax.tree.leaves(new)):
                 np.testing.assert_array_equal(np.asarray(o)[1::2],

@@ -263,6 +263,27 @@ def test_streaming_mixed_compressors_byte_accounting():
     assert max(res["batch_sizes"]) > 1
 
 
+def test_failing_flush_fails_the_run_fast(monkeypatch):
+    """A flush that raises ends the serve loop: the error is recorded,
+    the blocked clients are released by error frames, and run_streaming
+    raises the original exception well inside the clients' 60 s reply
+    timeout."""
+    from repro.runtime.server import StreamingServer
+
+    class FlushBoom(Exception):
+        pass
+
+    def boom(self, items):
+        raise FlushBoom("flush failed")
+
+    monkeypatch.setattr(StreamingServer, "_process", boom)
+    cfg = _smoke_cfg(compressor="topk", k=8)
+    t0 = time.monotonic()
+    with pytest.raises(FlushBoom, match="flush failed"):
+        run_streaming(cfg, n_clients=3, prompt_len=2, gen=2, max_batch=2)
+    assert time.monotonic() - t0 < 30.0
+
+
 def test_streaming_sessions_outnumber_max_batch():
     """More sessions than the flush size -> multiple ragged flushes, every
     session still completes with its own cache intact."""

@@ -4,7 +4,7 @@ import pathlib
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 from repro.core import compressors as C, wire
