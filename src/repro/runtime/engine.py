@@ -255,11 +255,12 @@ def run_streaming(cfg: ArchConfig, *, n_clients: int = 8, prompt_len: int = 4,
         "batch_sizes": server.batch_sizes,
         "fault_counters": fault_summary(server, clients),
         "metrics": registry.snapshot(),
-        # serve-loop wall seconds by stage (host staging [+ mixed-meta
-        # decode dispatch] / fused-or-plain step incl. token readback /
-        # reply framing+send), the token count those flushes served (for
-        # per-token stage costs), host staging-vs-wire byte totals, and
-        # per-client request->token round-trip latencies
+        # serve-loop wall seconds by stage (queue wait / prepare / host
+        # staging [+ mixed-meta decode dispatch] / fused-or-plain step =
+        # dispatch + token readback sync / reply framing+send), the token
+        # count those flushes served (for per-token stage costs), host
+        # staging-vs-wire byte totals, and per-client request->token
+        # round-trip latencies
         "stage_s": dict(server.stage_s),
         "stage_tokens": server.stage_tokens,
         "host_bytes": dict(server.host_bytes),

@@ -38,7 +38,6 @@ from __future__ import annotations
 
 import collections
 import threading
-import time
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -49,8 +48,9 @@ from repro.core import wire
 from repro.core.payload import Payload
 from repro.obs.registry import DEFAULT_REGISTRY, MetricsRegistry
 from repro.obs.trace import (EVT_SLOT_ADMIT, EVT_SLOT_EVICT, NULL_TRACER,
-                             SERVE_TID, SPAN_DECODE, SPAN_QUEUE_WAIT,
-                             SPAN_REPLY, SPAN_STEP, session_tid)
+                             SERVE_TID, SPAN_DECODE, SPAN_DISPATCH,
+                             SPAN_PREPARE, SPAN_QUEUE_WAIT, SPAN_REPLY,
+                             SPAN_STEP, SPAN_SYNC, SPAN_WAIT, session_tid)
 from repro.runtime import steps
 from repro.runtime.arena import SlotArena
 from repro.runtime.batching import BatchingQueue
@@ -65,6 +65,40 @@ from repro.testing.clock import Clock, SYSTEM_CLOCK
 #: the fetch runs before the restore, so the restore always writes real
 #: host state.
 _EVICTING = object()
+
+
+class _Stage:
+    """One stage of the serve loop (`server.wait` .. `server.reply`).
+
+    Stages share the server's stamp cursor `_t`: a stage opens at the
+    stamp that closed the one before it, and a leaf stage reads the clock
+    once as it closes. So each boundary is read once, and that one stamp
+    feeds `stage_s`, the tracer's span and its profiler annotation alike;
+    the stages tile the loop with no gap. A parent stage (`server.step`)
+    closes at its last child's stamp. `stage_s` keys drop the `server.`
+    prefix."""
+
+    __slots__ = ("_srv", "_key", "_leaf", "span", "_t0")
+
+    def __init__(self, srv: "StreamingServer", name: str, leaf: bool = True,
+                 **args):
+        self._srv = srv
+        self._key = name.split(".", 1)[1]
+        self._leaf = leaf
+        self.span = srv.tracer.span(name, tid=SERVE_TID, **args)
+
+    def __enter__(self) -> "_Stage":
+        self._t0 = self._srv._t
+        self.span.begin(self._t0)
+        return self
+
+    def __exit__(self, *exc):
+        srv = self._srv
+        if self._leaf:
+            srv._t = srv.clock.monotonic()
+        self.span.end(srv._t)
+        srv.stage_s[self._key] += srv._t - self._t0
+        return False
 
 
 def jit_serving_steps(top_step: Callable, *, dtype,
@@ -348,12 +382,26 @@ class StreamingServer(FrameServerBase):
         self.dtype = dtype
         self.backend = backend              # sparse-decode backend dispatch
         self.batch_sizes: List[int] = []    # flush fill history
-        self.stage_s = {"decode": 0.0, "step": 0.0, "reply": 0.0}
+        # serve-loop seconds by stage (`_Stage`, on `clock`): blocked on
+        # the queue, prepare, staging (+ mixed-meta decodes), step
+        # (= dispatch + sync), reply
+        self.stage_s = {"decode": 0.0, "step": 0.0, "reply": 0.0,
+                        "wait": 0.0, "prepare": 0.0, "dispatch": 0.0,
+                        "sync": 0.0}
         self.stage_tokens = 0               # tokens served by those flushes
         #   (normalizes stage_s to per-token stage costs in the bench)
+        self.pickups = 0                    # batches taken off the queue;
+        #   a pickup's index is the `flush` arg of its spans
+        self._t = 0.0                       # `_Stage` stamp cursor
+        self._t_pickup: Optional[float] = None  # stamp that closed the
+        #   `server.wait` whose batch `_process` serves next
         self._init_connections(BatchingQueue(max_batch, max_wait,
                                              clock=clock),
                                tracer=tracer, registry=registry)
+        # sessions resident in the arena, summed over the flushes that
+        # step (over `flush_fill`'s sum of rows: the share of resident
+        # sessions each flush serves)
+        self._m_resident = self.registry.counter("flush_resident_total")
         if tracer.enabled:
             tracer.name_track(SERVE_TID, "serve loop")
         self.arena: Optional[SlotArena] = None
@@ -540,12 +588,17 @@ class StreamingServer(FrameServerBase):
         an error frame, so a client blocked on its reply fails now instead
         of waiting out its reply timeout."""
         try:
+            self._t = self.clock.monotonic()
             while True:
-                batch = self.queue.get_batch(idle_timeout=0.05)
-                if batch:
-                    self._process(batch)
-                elif self.queue.drained:
+                with _Stage(self, SPAN_WAIT, flush=self.pickups) as wait:
+                    batch = self.queue.get_batch(idle_timeout=0.05)
+                    while not batch and not self.queue.drained:
+                        batch = self.queue.get_batch(idle_timeout=0.05)
+                    wait.span.note(n=len(batch))
+                if not batch:
                     return
+                self._t_pickup = self._t
+                self._process(batch)
         except Exception as e:          # surfaced by the engine
             with self._lock:
                 self.errors.append(e)
@@ -660,119 +713,125 @@ class StreamingServer(FrameServerBase):
             backend=self.backend, mesh=self._mesh)
 
     def _process(self, items) -> None:
-        # queue-wait accounting for every frame this flush picked up
-        # (including replays the dedup below drops — they waited too)
-        t_flush = self.clock.monotonic()
+        """Serve one batch, picked up at the stamp that closed
+        `server.wait` (read here when the caller drives the loop itself,
+        as the loadgen does)."""
+        t_flush, self._t_pickup = self._t_pickup, None
+        if t_flush is None:
+            t_flush = self._t = self.clock.monotonic()
+        flush = self.pickups
+        self.pickups += 1
         trace = self.tracer.enabled
-        for sess, frame in items:
-            t_enq = self._enq_ts.pop((sess.id, frame.seq), None)
-            if t_enq is None:
-                continue
-            self._m_qwait.observe((t_flush - t_enq) * 1e3)
-            if trace:
-                self.tracer.complete(SPAN_QUEUE_WAIT, t_enq, t_flush,
-                                     tid=session_tid(sess.id), sid=sess.id,
-                                     seq=frame.seq)
-        self._m_depth.set(len(self.queue))
-        all_items = items
-        items = self._dedup(items)
-        with self._lock:
-            # drain the in-flight count for EVERY frame this flush picked
-            # up (dedup-dropped replays included — they were enqueued too)
-            # and stamp activity for the LRU eviction order
-            for sess, _frame in all_items:
-                sess.pending -= 1
-                sess.last_active = t_flush
-            # eager slot release: a closed session's row returns to the
-            # free deque now, not at the next full-arena admission scan
-            for sess in self.sessions.values():
-                if sess.closed and sess.slot >= 0:
-                    slot, sess.slot = sess.slot, -1
-                    self._arena_ops.append(("reset", None, slot))
-                    self._push_free(slot)
-                    self.registry.counter("slot_reclaims_total").inc()
-                    self.tracer.instant(EVT_SLOT_EVICT, tid=SERVE_TID,
-                                        sid=sess.id, slot=slot)
-            if len(self._free_slots) == self._capacity:
-                # fully idle: compact the free list back to issue order
-                self._free_slots = collections.deque(
-                    sorted(self._free_slots))
-            ops, self._arena_ops = self._arena_ops, []
-            self._slot_cv.notify_all()
-            # a reclaimed slot means the session closed; any straggler
-            # frame has no device state left and is dropped. The slot is
-            # SNAPSHOTTED under the same lock: a reader thread admitting a
-            # new session may reclaim a closed session's slot at any
-            # moment, and a slot flipping to -1 between the filter and the
-            # mask build would corrupt another live slot's row.
-            items = [(s, f, s.slot) for s, f in items if s.slot >= 0]
-        if items:
-            self._ensure_arena(items[0][1].payload.meta.d)
-        self._apply_arena_ops(ops)      # serialized with the step here
-        if not items:
+        with _Stage(self, SPAN_PREPARE, flush=flush, n=len(items)):
+            # queue-wait accounting for every frame this flush picked up
+            # (including replays the dedup below drops — they waited too)
+            for sess, frame in items:
+                t_enq = self._enq_ts.pop((sess.id, frame.seq), None)
+                if t_enq is None:
+                    continue
+                self._m_qwait.observe((t_flush - t_enq) * 1e3)
+                if trace:
+                    self.tracer.complete(SPAN_QUEUE_WAIT, t_enq, t_flush,
+                                         tid=session_tid(sess.id),
+                                         sid=sess.id, seq=frame.seq,
+                                         flush=flush)
+            self._m_depth.set(len(self.queue))
+            all_items = items
+            items = self._dedup(items)
+            with self._lock:
+                # drain the in-flight count for EVERY frame this flush
+                # picked up (dedup-dropped replays included — they were
+                # enqueued too) and stamp activity for the LRU eviction
+                # order
+                for sess, _frame in all_items:
+                    sess.pending -= 1
+                    sess.last_active = t_flush
+                # eager slot release: a closed session's row returns to
+                # the free deque now, not at the next full-arena admission
+                # scan
+                for sess in self.sessions.values():
+                    if sess.closed and sess.slot >= 0:
+                        slot, sess.slot = sess.slot, -1
+                        self._arena_ops.append(("reset", None, slot))
+                        self._push_free(slot)
+                        self.registry.counter("slot_reclaims_total").inc()
+                        self.tracer.instant(EVT_SLOT_EVICT, tid=SERVE_TID,
+                                            sid=sess.id, slot=slot)
+                # every slot not free is held by an open session now
+                resident = self._capacity - len(self._free_slots)
+                if resident == 0:
+                    # fully idle: compact the free list back to ascending order
+                    self._free_slots = collections.deque(
+                        sorted(self._free_slots))
+                ops, self._arena_ops = self._arena_ops, []
+                self._slot_cv.notify_all()
+                # a reclaimed slot means the session closed; any straggler
+                # frame has no device state left and is dropped. The slot
+                # is SNAPSHOTTED under the same lock: a reader thread
+                # admitting a new session may reclaim a closed session's
+                # slot at any moment, and a slot flipping to -1 between
+                # the filter and the mask build would corrupt another live
+                # slot's row.
+                items = [(s, f, s.slot) for s, f in items if s.slot >= 0]
+            if items:
+                self._ensure_arena(items[0][1].payload.meta.d)
+            self._apply_arena_ops(ops)      # serialized with the step here
+            n = len(items)
+            if n:
+                self.batch_sizes.append(n)
+                self._m_fill.observe(n)
+                self._m_resident.inc(resident)
+        if not n:
             return
-        self.batch_sizes.append(len(items))
-        self._m_fill.observe(len(items))
-        if trace:
-            ts0 = self.clock.monotonic()
-        t0 = time.perf_counter()
-        by_meta: Dict = {}
-        for i, (_, frame, _slot) in enumerate(items):
-            by_meta.setdefault(frame.payload.meta, []).append(i)
-        active = np.zeros(self.arena.capacity, bool)
-        for _, _, slot in items:
-            active[slot] = True
-        if len(by_meta) == 1:
-            # single-meta flush: ONE fused dispatch — decode lands in
-            # xbuf[slots] and the donated whole-arena step runs in the
-            # same program; only the (capacity, 1) token rows come back
-            [(meta, idxs)] = by_meta.items()
-            stacked, slots = self._stack_group(
-                meta, [items[i][1].payload for i in idxs],
-                np.fromiter((self.arena.wire_row(items[i][2])
-                             for i in idxs), np.int64, len(idxs)),
-                self._bucket(len(idxs)))
-            if trace:
-                ts1 = self.clock.monotonic()
-            t1 = time.perf_counter()
-            tokens, self.arena.xbuf, self.arena.cache = self._fused_step(
-                self.params, self.arena.xbuf, stacked, slots,
-                self.arena.cache, jnp.asarray(active))
-        else:
-            # mixed-meta flush: per-meta device decodes, then the donated
-            # step over the whole arena — no cache stack/unstack either way
-            for meta, idxs in by_meta.items():
-                self._decode_group(
+        stacked = None
+        with _Stage(self, SPAN_DECODE, flush=flush, n=n):
+            by_meta: Dict = {}
+            for i, (_, frame, _slot) in enumerate(items):
+                by_meta.setdefault(frame.payload.meta, []).append(i)
+            active = np.zeros(self.arena.capacity, bool)
+            for _, _, slot in items:
+                active[slot] = True
+            if len(by_meta) == 1:
+                # single-meta flush: staging only — the decode runs inside
+                # the fused dispatch below
+                [(meta, idxs)] = by_meta.items()
+                stacked, slots = self._stack_group(
                     meta, [items[i][1].payload for i in idxs],
                     np.fromiter((self.arena.wire_row(items[i][2])
-                                 for i in idxs), np.int64, len(idxs)))
-            if trace:
-                ts1 = self.clock.monotonic()
-            t1 = time.perf_counter()
-            tokens, self.arena.cache = self.top_step(
-                self.params, self.arena.xbuf, self.arena.cache,
-                jnp.asarray(active))
-        tokens = np.asarray(tokens)
-        if trace:
-            ts2 = self.clock.monotonic()
-        t2 = time.perf_counter()
-        for sess, frame, slot in items:
-            # with a pod axis, the token row returned on the inverse ring
-            # to the slot's ingestion block (SlotArena.wire_row; identity
-            # otherwise)
-            reply = wire.encode_token_frame(sess.id, frame.seq,
-                                            tokens[self.arena.wire_row(slot)])
-            sess.last_seq, sess.last_reply = frame.seq, reply
-            sess.endpoint.send(reply)
-            self._count_frame_down(sess, len(reply))
-        t3 = time.perf_counter()
-        self.stage_s["decode"] += t1 - t0
-        self.stage_s["step"] += t2 - t1
-        self.stage_s["reply"] += t3 - t2
-        self.stage_tokens += len(items)
-        if trace:
-            ts3 = self.clock.monotonic()
-            n = len(items)
-            self.tracer.complete(SPAN_DECODE, ts0, ts1, tid=SERVE_TID, n=n)
-            self.tracer.complete(SPAN_STEP, ts1, ts2, tid=SERVE_TID, n=n)
-            self.tracer.complete(SPAN_REPLY, ts2, ts3, tid=SERVE_TID, n=n)
+                                 for i in idxs), np.int64, len(idxs)),
+                    self._bucket(len(idxs)))
+            else:
+                # mixed-meta flush: per-meta device decodes, then the
+                # donated plain step below — no cache stack/unstack
+                for meta, idxs in by_meta.items():
+                    self._decode_group(
+                        meta, [items[i][1].payload for i in idxs],
+                        np.fromiter((self.arena.wire_row(items[i][2])
+                                     for i in idxs), np.int64, len(idxs)))
+        with _Stage(self, SPAN_STEP, leaf=False, flush=flush, n=n):
+            with _Stage(self, SPAN_DISPATCH, flush=flush, n=n):
+                if stacked is not None:
+                    # ONE fused dispatch — decode lands in xbuf[slots] and
+                    # the donated whole-arena step runs in the same
+                    # program; only the (capacity, 1) token rows come back
+                    tokens, self.arena.xbuf, self.arena.cache = \
+                        self._fused_step(self.params, self.arena.xbuf,
+                                         stacked, slots, self.arena.cache,
+                                         jnp.asarray(active))
+                else:
+                    tokens, self.arena.cache = self.top_step(
+                        self.params, self.arena.xbuf, self.arena.cache,
+                        jnp.asarray(active))
+            with _Stage(self, SPAN_SYNC, flush=flush, n=n):
+                tokens = np.asarray(tokens)
+        with _Stage(self, SPAN_REPLY, flush=flush, n=n):
+            for sess, frame, slot in items:
+                # with a pod axis, the token row returned on the inverse
+                # ring to the slot's ingestion block (SlotArena.wire_row;
+                # identity otherwise)
+                reply = wire.encode_token_frame(
+                    sess.id, frame.seq, tokens[self.arena.wire_row(slot)])
+                sess.last_seq, sess.last_reply = frame.seq, reply
+                sess.endpoint.send(reply)
+                self._count_frame_down(sess, len(reply))
+        self.stage_tokens += n
