@@ -53,10 +53,19 @@ def attention_spec(cfg: ArchConfig, *, cross=False, gated=False):
 
 
 def _project_qkv(p, cfg: ArchConfig, xq, xkv, q_positions, kv_positions, *, rope=True):
+    return _qkv_heads(p, cfg, xq @ p["wq"].astype(xq.dtype),
+                      xkv @ p["wk"].astype(xkv.dtype),
+                      xkv @ p["wv"].astype(xkv.dtype), q_positions,
+                      kv_positions, rope=rope)
+
+
+def _qkv_heads(p, cfg: ArchConfig, q, k, v, q_positions, kv_positions, *,
+               rope=True):
+    """Projected q, k, v (..., heads * hd) -> per-head, qk-normed, rotated."""
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = (xq @ p["wq"].astype(xq.dtype)).reshape(*xq.shape[:-1], hq, hd)
-    k = (xkv @ p["wk"].astype(xkv.dtype)).reshape(*xkv.shape[:-1], hkv, hd)
-    v = (xkv @ p["wv"].astype(xkv.dtype)).reshape(*xkv.shape[:-1], hkv, hd)
+    q = q.reshape(*q.shape[:-1], hq, hd)
+    k = k.reshape(*k.shape[:-1], hkv, hd)
+    v = v.reshape(*v.shape[:-1], hkv, hd)
     if cfg.qk_norm:
         q = common.rms_norm(q, p["q_norm"]["scale"])
         k = common.rms_norm(k, p["k_norm"]["scale"])
@@ -66,22 +75,26 @@ def _project_qkv(p, cfg: ArchConfig, xq, xkv, q_positions, kv_positions, *, rope
     return q, k, v
 
 
-def _sdpa(q, k, v, mask, cfg: ArchConfig):
+def _sdpa(q, k, v, mask, cfg: ArchConfig, kv_axes: str = "bkhd"):
     """q: (B,Sq,Hq,hd), k/v: (B,Skv,Hkv,hd), mask: (B?,1?,Sq,Skv) bool.
+    `kv_axes="bhkd"` takes k/v as (B,Hkv,Skv,hd), the decode cache layout.
+    The logits scale is the config's head dim; q, k and v may carry zero
+    padding past it (the decode cache's lane padding), which the
+    output keeps.
 
     bf16 operands with f32 accumulation (MXU semantics) — avoids hauling
     f32 copies of q/k/v through HBM and collectives."""
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     g = hq // hkv
-    B, Sq = q.shape[0], q.shape[1]
-    qg = q.reshape(B, Sq, hkv, g, hd)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+    B, Sq, width = q.shape[0], q.shape[1], q.shape[-1]
+    qg = q.reshape(B, Sq, hkv, g, width)
+    logits = jnp.einsum(f"bqhgd,{kv_axes}->bhgqk", qg, k,
                         preferred_element_type=jnp.float32) / (hd ** 0.5)
     logits = jnp.where(mask[:, None, None, :, :], logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", w.astype(v.dtype), v,
+    out = jnp.einsum(f"bhgqk,{kv_axes}->bqhgd", w.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, Sq, hq, hd).astype(q.dtype)
+    return out.reshape(B, Sq, hq, width).astype(q.dtype)
 
 
 def _causal_mask(q_pos, kv_pos, window: int):
@@ -165,14 +178,32 @@ def cross_kv(p, cfg: ArchConfig, kv_tokens):
 # Decode (single new token against a cache)
 # --------------------------------------------------------------------------
 
+#: the TPU's lane count: the decode cache's head dim is zero-padded to a
+#: multiple of it
+LANES = 128
+
+
+def kv_width(cfg: ArchConfig) -> int:
+    """The decode cache's head dim: `cfg.hd` rounded up to `LANES`."""
+    return -(-cfg.hd // LANES) * LANES
+
+
 def init_kv_cache(cfg: ArchConfig, batch: int, max_len: int,
                   *, bits: int = 16):
-    """Rolling cache; for sliding-window archs max_len = window size.
+    """Rolling cache, (batch, kv heads, positions, `kv_width`); for
+    sliding-window archs max_len = window size.
 
-    bits=8 stores int8 codes + per-(token, head) f32 scales (symmetric
+    The layout lets a step write one position of every head in place and
+    read each head's (positions, head dim) matrix with no relayout on the
+    TPU: heads sit outside the positions, and the head dim is zero-padded
+    to whole lanes (at 96, the TPU would otherwise lay the positions out
+    minor, and a write of one position would copy the whole cache to
+    another layout and back).
+
+    bits=8 stores int8 codes + per-(head, token) f32 scales (symmetric
     quantization) — halves decode HBM footprint; dequantized on read."""
     size = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
-    shape = (batch, size, cfg.n_kv_heads, cfg.hd)
+    shape = (batch, cfg.n_kv_heads, size, kv_width(cfg))
     if bits == 8:
         return {
             "k": jnp.zeros(shape, jnp.int8),
@@ -187,7 +218,7 @@ def init_kv_cache(cfg: ArchConfig, batch: int, max_len: int,
 
 
 def _quantize_kv(x):
-    """x: (B, 1, H, hd) -> (int8 codes, (B, 1, H) scale)."""
+    """x: (..., hd) -> (int8 codes, (...) scale), one scale per vector."""
     scale = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1) / 127.0
     safe = jnp.maximum(scale, 1e-9)
     code = jnp.clip(jnp.round(x.astype(jnp.float32) / safe[..., None]),
@@ -204,44 +235,54 @@ def kv_cache_spec(rt: Runtime, *, bits: int = 16):
     # (GQA kv-head counts of 4-8 cannot split a 16-way axis and would force
     # full replication -> 16x the per-chip cache); each rank attends over its
     # sequence slice and the softmax reductions lower to psums.
-    spec = {"k": rt.pspec("batch", "flashdecode", None, None),
-            "v": rt.pspec("batch", "flashdecode", None, None)}
+    spec = {"k": rt.pspec("batch", None, "flashdecode", None),
+            "v": rt.pspec("batch", None, "flashdecode", None)}
     if bits == 8:
-        spec["k_scale"] = rt.pspec("batch", "flashdecode", None)
-        spec["v_scale"] = rt.pspec("batch", "flashdecode", None)
+        spec["k_scale"] = rt.pspec("batch", None, "flashdecode")
+        spec["v_scale"] = rt.pspec("batch", None, "flashdecode")
     return spec
 
 
-def decode_attention(p, cfg: ArchConfig, rt: Runtime, x_tok, cache, pos):
-    """x_tok: (B, 1, d); cache: {'k','v'} rolling buffers; pos: scalar int32
-    (absolute position of the new token). Returns (y, new_cache)."""
-    B = x_tok.shape[0]
-    size = cache["k"].shape[1]
-    quant = "k_scale" in cache
-    q, k_new, v_new = _project_qkv(
-        p, cfg, x_tok, x_tok, jnp.full((1, 1), pos), jnp.full((1, 1), pos))
-    slot = (pos % size).astype(jnp.int32)
-    new_cache = {}
-    if quant:
-        kc, ks = _quantize_kv(k_new)
-        vc, vs = _quantize_kv(v_new)
-        kcode = jax.lax.dynamic_update_slice(cache["k"], kc, (0, slot, 0, 0))
-        vcode = jax.lax.dynamic_update_slice(cache["v"], vc, (0, slot, 0, 0))
-        kscale = jax.lax.dynamic_update_slice(cache["k_scale"], ks,
-                                              (0, slot, 0))
-        vscale = jax.lax.dynamic_update_slice(cache["v_scale"], vs,
-                                              (0, slot, 0))
-        new_cache.update(k=kcode, v=vcode, k_scale=kscale, v_scale=vscale)
-        k = _dequantize_kv(kcode, kscale, x_tok.dtype)
-        v = _dequantize_kv(vcode, vscale, x_tok.dtype)
+def decode_qkv(p, cfg: ArchConfig, x_tok, pos, *, quant: bool):
+    """The new token's query and its cache entries. x_tok: (B, 1, d); pos:
+    scalar int32 (absolute position of the new token). Returns (q, entries)
+    with q (B, 1, Hq, kv_width) and entries keyed and laid out like the
+    cache leaves, one position each: 'k', 'v' (B, Hkv, 1, kv_width), and
+    with `quant` the int8 codes plus 'k_scale', 'v_scale' (B, Hkv, 1). The
+    head dim's padding is zeros (codes 0; a scale is the real values')."""
+    # the projections leave the MXU as they are: past this boundary the
+    # TPU would pick a transposed layout for them and re-lay out wq, wk
+    # and wv of every layer on every step
+    proj = jax.lax.optimization_barrier(
+        tuple(x_tok @ p[w].astype(x_tok.dtype) for w in ("wq", "wk", "wv")))
+    q, k_new, v_new = _qkv_heads(p, cfg, *proj, jnp.full((1, 1), pos),
+                                 jnp.full((1, 1), pos))
+    pad = [(0, 0)] * 3 + [(0, kv_width(cfg) - cfg.hd)]
+    q, k_new, v_new = (jnp.pad(a, pad) for a in (q, k_new, v_new))
+    k_new, v_new = k_new.swapaxes(1, 2), v_new.swapaxes(1, 2)
+    if not quant:
+        return q, {"k": k_new, "v": v_new}
+    kc, ks = _quantize_kv(k_new)
+    vc, vs = _quantize_kv(v_new)
+    return q, {"k": kc, "v": vc, "k_scale": ks, "v_scale": vs}
+
+
+def decode_attend(p, cfg: ArchConfig, rt: Runtime, q, cache, pos, dtype):
+    """Attend the new token's query (`decode_qkv`'s, lane-padded) over a
+    rolling cache that already holds its entries at slot `pos % size`.
+    Returns y (B, 1, d) in `dtype`."""
+    B = q.shape[0]
+    size = cache["k"].shape[2]
+    if "k_scale" in cache:
+        k = _dequantize_kv(cache["k"], cache["k_scale"], dtype)
+        v = _dequantize_kv(cache["v"], cache["v_scale"], dtype)
     else:
-        k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
-        new_cache.update(k=k, v=v)
-    k = rt.shard(k, "batch", "flashdecode", None, None)
-    v = rt.shard(v, "batch", "flashdecode", None, None)
+        k, v = cache["k"], cache["v"]
+    k = rt.shard(k, "batch", None, "flashdecode", None)
+    v = rt.shard(v, "batch", None, "flashdecode", None)
 
     # valid slots: absolute positions of each slot given the ring layout
+    slot = (pos % size).astype(jnp.int32)
     idx = jnp.arange(size)
     wraps = jnp.where(idx <= slot, pos - slot, pos - size - slot)
     abs_pos = idx + wraps              # absolute position stored in each slot
@@ -249,6 +290,19 @@ def decode_attention(p, cfg: ArchConfig, rt: Runtime, x_tok, cache, pos):
     if cfg.sliding_window:
         valid &= abs_pos > pos - cfg.sliding_window
     mask = jnp.broadcast_to(valid[None, None, :], (B, 1, size))
-    out = _sdpa(q, k, v, mask, cfg)
-    y = out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["wo"].astype(x_tok.dtype)
-    return rt.shard(y, "batch", None, None), new_cache
+    out = _sdpa(q, k, v, mask, cfg, kv_axes="bhkd")[..., :cfg.hd]
+    y = out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["wo"].astype(dtype)
+    return rt.shard(y, "batch", None, None)
+
+
+def decode_attention(p, cfg: ArchConfig, rt: Runtime, x_tok, cache, pos):
+    """x_tok: (B, 1, d); cache: {'k','v'} rolling buffers; pos: scalar int32
+    (absolute position of the new token). Returns (y, new_cache)."""
+    q, entries = decode_qkv(p, cfg, x_tok, pos, quant="k_scale" in cache)
+    slot = (pos % cache["k"].shape[2]).astype(jnp.int32)
+    new_cache = {
+        name: jax.lax.dynamic_update_slice(
+            cache[name], e, (0, 0, slot) + (0,) * (e.ndim - 3))
+        for name, e in entries.items()}
+    y = decode_attend(p, cfg, rt, q, new_cache, pos, x_tok.dtype)
+    return y, new_cache

@@ -394,6 +394,16 @@ def cache_spec(cfg: ArchConfig, rt: Runtime):
     return spec
 
 
+def _decode_ffn(pl, cfg: ArchConfig, rt: Runtime, x):
+    """The feed-forward half of one dense/moe decode layer, residual added."""
+    nf = _norm(cfg)
+    if cfg.family == "moe":
+        y, _ = moe.moe(pl["moe"], cfg, rt, nf(x, pl["moe"]["norm"]))
+    else:
+        y = mlp.mlp(pl["mlp"], cfg, rt, nf(x, pl["mlp"]["norm"]))
+    return x + y
+
+
 def decode_layers(params, cfg: ArchConfig, rt: Runtime, x, cache, lo, hi):
     """One-token pass through layers [lo, hi). Returns (x, partial caches)."""
     nf = _norm(cfg)
@@ -408,12 +418,7 @@ def decode_layers(params, cfg: ArchConfig, rt: Runtime, x, cache, lo, hi):
             pl, kvl = inp
             y, kv_new = attention.decode_attention(
                 pl["attn"], cfg, rt, nf(x, pl["attn"]["norm"]), kvl, pos)
-            x = x + y
-            if cfg.family == "moe":
-                y2, _ = moe.moe(pl["moe"], cfg, rt, nf(x, pl["moe"]["norm"]))
-            else:
-                y2 = mlp.mlp(pl["mlp"], cfg, rt, nf(x, pl["mlp"]["norm"]))
-            return x + y2, kv_new
+            return _decode_ffn(pl, cfg, rt, x + y), kv_new
 
         x, kv_out = jax.lax.scan(body, x, (stack, kv))
         new_cache["kv"] = kv_out
@@ -534,6 +539,73 @@ def decode_layers(params, cfg: ArchConfig, rt: Runtime, x, cache, lo, hi):
         return x, new_cache
 
     raise ValueError(cfg.family)
+
+
+@partial(jax.jit, static_argnames=("cfg", "rt"))
+def _decode_layer_in_place(layers, x, kv, pos, rows, ring, layer, *,
+                           cfg: ArchConfig, rt: Runtime):
+    """One layer of `decode_rows_in_place`. `layer` is traced, so every
+    layer (and every program that steps the same arena) reuses one trace;
+    XLA inlines the call and folds the constant index."""
+    nf = _norm(cfg)
+    pl = jax.tree_util.tree_map(lambda a: a[layer], layers)
+
+    def qkv(x, pos):
+        return attention.decode_qkv(pl["attn"], cfg,
+                                    nf(x, pl["attn"]["norm"]), pos,
+                                    quant="k_scale" in kv)
+
+    def rest(x, q, kvl, pos):
+        y = attention.decode_attend(pl["attn"], cfg, rt, q, kvl, pos,
+                                    x.dtype)
+        return _decode_ffn(pl, cfg, rt, x + y)
+
+    q, entries = jax.vmap(qkv)(x, pos)
+    heads = jnp.arange(cfg.n_kv_heads)[None, :]
+    # (row, layer, batch 0, head, ring slot) <- (C, Hkv[, hd])
+    kv = {name: leaf.at[rows, layer, 0, heads, ring].set(
+              entries[name][:, 0, :, 0], mode="drop")
+          for name, leaf in kv.items()}
+    x = jax.vmap(rest)(x, q, {n: a[:, layer] for n, a in kv.items()}, pos)
+    # the residual stream is rounded to its dtype between layers, as
+    # `decode_layers`' scan carry is: no fusion across the boundary may
+    # keep it at a wider precision
+    return jax.lax.optimization_barrier(x), kv
+
+
+def decode_rows_in_place(params, cfg: ArchConfig, rt: Runtime, x, cache,
+                         active, lo: int, hi: int):
+    """One-token pass of dense/moe layers [lo, hi) over a stack of
+    independent rows, each with its own ring KV cache and position, whose
+    new K/V entries are written into the row-stacked cache in place.
+
+    x: (C, 1, 1, d); cache: {'pos': (C,) int32, 'kv': leaves stacked
+    (C, L, 1, Hkv, size, ...)}, as `SlotArena` stacks `init_cache(batch=1)`;
+    active: (C,) bool. Returns (x, new cache).
+
+    Each layer projects every row's q and K/V, scatters the active rows'
+    entries to (row, layer, pos % size) (an inactive row's index is out of
+    range and its write is dropped), then attends each row over its layer
+    of the updated cache. Per row the math is `decode_layers`' (the same
+    per-row functions, vmapped), so tokens and written entries match it;
+    the cache moves only where a row writes, so under a donated jit the
+    leaves update in place with no whole-cache select or copy. Layers
+    outside [lo, hi), inactive rows and every other position keep their
+    bits; `pos` advances on active rows only. The layer loop is unrolled
+    (the layer index is a constant to XLA), so no scan slices or restacks
+    the cache.
+    """
+    pos = cache["pos"]
+    kv = dict(cache["kv"])
+    n_rows, size = pos.shape[0], kv["k"].shape[4]
+    # scatter index per (row, head): an inactive row's is out of range
+    rows = jnp.where(active, jnp.arange(n_rows), n_rows)[:, None]
+    ring = (pos % size).astype(jnp.int32)[:, None]
+    for layer in range(lo, hi):
+        x, kv = _decode_layer_in_place(params["layers"], x, kv, pos, rows,
+                                       ring, jnp.int32(layer), cfg=cfg,
+                                       rt=rt)
+    return x, {**cache, "kv": kv, "pos": jnp.where(active, pos + 1, pos)}
 
 
 def decode_step(params, cfg: ArchConfig, rt: Runtime, token, cache):

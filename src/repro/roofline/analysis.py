@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
+from repro.models import attention
 from repro.roofline import hlo as hlo_mod
 
 PEAK_FLOPS = 197e12          # bf16 / chip
@@ -210,14 +211,15 @@ def serving_step_costs(cfg, cut: int, capacity: int, max_len: int,
                        state_nbytes: int):
     """Predicted (flops, bytes floor) of the fused decode+step program.
 
-    flops: every arena row computes (inactive rows are masked afterwards),
+    flops: every arena row computes (inactive rows drop their writes),
     each paying the top matmul params plus the two decode-attention dots
-    against a `max_len` KV cache — exact to `FUSED_FLOPS_RTOL`.
+    against a `max_len` KV cache of lane-padded heads
+    (`attention.kv_width`) — exact to `FUSED_FLOPS_RTOL`.
     bytes floor: the arena state written + read (`state_nbytes` = cache
     leaves + xbuf, measured off the live arrays so an int8 KV arena
     predicts its smaller traffic automatically); measured lands within
     `FUSED_BYTES_BAND` of it."""
-    score_dots = 2 * cfg.n_heads * cfg.hd * max_len
+    score_dots = 2 * cfg.n_heads * attention.kv_width(cfg) * max_len
     flops = 2.0 * capacity * (top_matmul_params(cfg, cut) + score_dots)
     return flops, 2.0 * state_nbytes
 

@@ -29,8 +29,9 @@ Aliasing/donation invariants (also in docs/performance.md):
     group padding scatters into (a cached zero row, NEVER an alias of a
     live session's data), keeping one compile per payload meta regardless
     of flush fill.
-  * inactive slots pass through the top step unchanged (the mask selects
-    the old leaf), so stale `xbuf` rows from earlier flushes are never
+  * the top step writes each active slot's new K/V entries in place and
+    drops an inactive slot's write, so inactive slots pass through it
+    unchanged and stale `xbuf` rows from earlier flushes are never
     observable.
 
 Slot lifecycle is owned by the server (admission, closed-slot reclaim, LRU
@@ -142,6 +143,11 @@ class SlotArena:
         row = jax.tree.map(jnp.asarray, state)
         self.cache = _write_slot(self.cache, row,
                                  jnp.asarray(slot, jnp.int32))
+
+    def release(self) -> None:
+        """Drop the device arrays (`cache`, `xbuf`) once the serve loop
+        has stopped; the arena is unusable afterwards."""
+        self.cache = self.xbuf = None
 
     def slot_cache(self, slot: int) -> Any:
         """Host copy of one slot's cache row (tests/debug only — the serve

@@ -608,6 +608,11 @@ class StreamingServer(FrameServerBase):
             for sess in sessions:
                 sess.endpoint.send(wire.encode_error_frame(
                     sess.id, 0, wire.ERR_PROTOCOL, msg))
+        finally:
+            # a stopped server gives the arena's device memory back, even
+            # while something (a load generator, a test) still holds it
+            if self.arena is not None:
+                self.arena.release()
 
     def warm(self, example_payloads) -> None:
         """Compile every hot-loop jit before the serving clock starts.

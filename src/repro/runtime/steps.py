@@ -8,9 +8,11 @@ so each party updates only its own slice of a full-shaped cache:
   * client (feature owner): embed -> layers [0, cut) -> `Compressor.encode`;
     writes the prefix slice.
   * server (label owner): dense cut view -> layers [cut, L) -> lm head ->
-    greedy token; writes the suffix slice. The server step is vmapped over a
+    greedy token; writes the suffix slice. The server step runs over a
     leading session axis so one compiled step serves a whole batch of
-    sessions, each row with its own cache and position.
+    sessions, each row with its own cache and position; the arena step
+    writes only each active row's new K/V entries, in place
+    (`make_arena_top_step`).
 """
 from __future__ import annotations
 
@@ -103,6 +105,39 @@ def make_top_step(cfg: ArchConfig, rt: Runtime, cut: int) -> Callable:
     return jax.vmap(one_session, in_axes=(None, 0, 0))
 
 
+def _make_arena_hidden(cfg: ArchConfig, rt: Runtime, cut: int) -> Callable:
+    """The arena step's top-layer pass, token head split out:
+    (params, x (C, 1, 1, d), cache arena stacked over C, active (C,) bool)
+    -> (hidden (C, 1, 1, d), new arena). Shared by the single-device step
+    and the sharded step's per-shard body.
+
+    Where the arena holds only stacked ring `kv` leaves and `pos` (dense,
+    moe), each active row's new K/V is written in place
+    (`transformer.decode_rows_in_place`): inactive rows' writes are
+    dropped, so nothing else of the arena is read back or rewritten.
+    Other caches (recurrent `mamba`/`rwkv` state, frozen `cross_kv`) run
+    the per-row layer pass over every row and keep the old leaves of
+    inactive rows with `where(active, new, old)`: recurrent state is
+    rewritten whole each step anyway."""
+
+    def masked_row(params, x, cache, active):
+        x, partial = transformer.decode_layers(params, cfg, rt, x, cache,
+                                               cut, cfg.n_layers)
+        new = _merge_range(cache, partial, prefix=False)
+        new = jax.tree.map(lambda n, o: jnp.where(active, n, o), new, cache)
+        return x, new
+
+    masked = jax.vmap(masked_row, in_axes=(None, 0, 0, 0))
+
+    def hidden(params, x, cache, active):
+        if set(cache) == {"pos", "kv"}:
+            return transformer.decode_rows_in_place(
+                params, cfg, rt, x, cache, active, cut, cfg.n_layers)
+        return masked(params, x, cache, active)
+
+    return hidden
+
+
 def make_arena_top_step(cfg: ArchConfig, rt: Runtime, cut: int,
                         mesh=None) -> Callable:
     """Whole-arena server step with an active-slot mask.
@@ -110,14 +145,17 @@ def make_arena_top_step(cfg: ArchConfig, rt: Runtime, cut: int,
     (params, xbuf (C+1, 1, 1, d), cache arena stacked over C, active (C,)
     bool) -> (tokens (C, 1) i32, new arena). Row i of the arena is session
     slot i; `xbuf`'s trailing scratch row (the decode-group pad target) is
-    sliced off before the step. Inactive slots compute and discard — their
-    new cache leaves are `where(active, new, old)`, so position/KV never
-    advance for a slot that received no frame this flush, and the output
-    arena aliases the donated input in place under
-    `jax.jit(..., donate_argnums=(2,))` (see `runtime.server`).
+    sliced off before the step. Position and KV never advance for a slot
+    that received no frame this flush: each active row's new K/V is
+    scattered to (slot, layer, pos % size) of the arena and an inactive
+    row's write is dropped (`_make_arena_hidden`), so under
+    `jax.jit(..., donate_argnums=(2,))` (see `runtime.server`) the output
+    arena aliases the donated input and only the written entries move.
+    Layers below `cut` are never touched.
 
-    Per-row numerics are identical to `make_top_step` (same vmapped body),
-    so arena-served tokens are bit-identical to the flush-stacked path.
+    Per-row numerics are identical to `make_top_step` (the same per-row
+    layer functions, vmapped), so arena-served tokens are bit-identical to
+    the flush-stacked path.
 
     With `mesh` (a `jax.sharding.Mesh`), the step runs under `shard_map`
     with arena rows sharded over every mesh axis and the lm head
@@ -129,24 +167,21 @@ def make_arena_top_step(cfg: ArchConfig, rt: Runtime, cut: int,
     pin tokens exact and KV within a tolerance). `mesh=None` is exactly
     the pre-mesh single-device program.
     """
+    if mesh is not None:
+        return _make_sharded_arena_step(cfg, rt, cut, mesh)
+    hidden = _make_arena_hidden(cfg, rt, cut)
 
-    def one_session(params, x, cache, active):
-        x, partial = transformer.decode_layers(params, cfg, rt, x, cache,
-                                               cut, cfg.n_layers)
-        logits = transformer.lm_head(params, cfg, rt, x)
-        tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        new = _merge_range(cache, partial, prefix=False)
-        new = jax.tree.map(lambda n, o: jnp.where(active, n, o), new, cache)
-        return tok, new
+    def token(params, h):
+        logits = transformer.lm_head(params, cfg, rt, h)
+        return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
 
-    vstep = jax.vmap(one_session, in_axes=(None, 0, 0, 0))
+    head = jax.vmap(token, in_axes=(None, 0))
 
-    if mesh is None:
-        def arena_step(params, xbuf, cache, active):
-            return vstep(params, xbuf[: active.shape[0]], cache, active)
+    def arena_step(params, xbuf, cache, active):
+        h, cache = hidden(params, xbuf[: active.shape[0]], cache, active)
+        return head(params, h), cache
 
-        return arena_step
-    return _make_sharded_arena_step(cfg, rt, cut, mesh)
+    return arena_step
 
 
 def _make_sharded_arena_step(cfg: ArchConfig, rt: Runtime, cut: int,
@@ -158,8 +193,9 @@ def _make_sharded_arena_step(cfg: ArchConfig, rt: Runtime, cut: int,
       * arena rows (slots) shard over ALL mesh axes flattened in mesh
         order — 'pod' x 'data' x 'model' — so session capacity scales
         with every device. Row sharding is batch decomposition: each
-        device runs the same per-row program `make_arena_top_step` vmaps,
-        no contraction is split, numerics are untouched.
+        device runs the single-device step's top-layer pass
+        (`_make_arena_hidden`) over its rows, no contraction is split,
+        numerics are untouched.
       * the lm head is tensor-parallel over 'model': each rank first
         all-gathers its row block along 'model' (`tp.gather_seq_local`'s
         collective, norm applied BEFORE the gather in Megatron-SP order),
@@ -196,16 +232,10 @@ def _make_sharded_arena_step(cfg: ArchConfig, rt: Runtime, cut: int,
             f"padded vocab {cfg.padded_vocab} not divisible by model axis "
             f"{n_model}")
 
-    def one_session_hidden(params, x, cache, active):
-        """Per-row top-layer pass, token head split out (it needs the
-        cross-rank collectives). Cache update identical to `one_session`."""
-        x, partial = transformer.decode_layers(params, cfg, rt, x, cache,
-                                               cut, cfg.n_layers)
-        new = _merge_range(cache, partial, prefix=False)
-        new = jax.tree.map(lambda n, o: jnp.where(active, n, o), new, cache)
-        return x, new
-
-    vhidden = jax.vmap(one_session_hidden, in_axes=(None, 0, 0, 0))
+    # the top-layer pass of the single-device step, over this shard's
+    # rows; the token head is split out (it needs the cross-rank
+    # collectives)
+    hidden = _make_arena_hidden(cfg, rt, cut)
 
     def body(params, x, cache, active):
         if n_pod > 1:
@@ -213,7 +243,7 @@ def _make_sharded_arena_step(cfg: ArchConfig, rt: Runtime, cut: int,
             # to the pod holding those slots' top-model state
             from repro.split import protocol
             x = jax.lax.ppermute(x, "pod", protocol.pod_ring_perm(n_pod))
-        h, new_cache = vhidden(params, x, cache, active)
+        h, new_cache = hidden(params, x, cache, active)
         h = common.apply_norm(h, params["final_norm"], cfg.norm)
         if n_model > 1:
             # reassemble the (pod, data) row block from the model ranks —

@@ -2,6 +2,8 @@
 path for every payload kind, zero host-side densification on the serving
 and training hot paths, slot stability under chaos/reconnect, slot reuse
 after close, and the active-mask no-advance invariant."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -331,6 +333,21 @@ def test_full_arena_evicts_lru_idle_session():
     assert server.registry.counter("slot_readmissions_total").value == re0 + 1
 
 
+def test_stopped_serve_loop_releases_the_arena():
+    """Once the serve loop has stopped, the server no longer pins the
+    arena's device arrays, though its owner still holds the server."""
+    import threading
+
+    server = _server(capacity=2)
+    assert server.arena.cache is not None
+    loop = threading.Thread(target=server.serve_loop, daemon=True)
+    loop.start()
+    server.shutdown()
+    loop.join(30.0)
+    assert not loop.is_alive()
+    assert server.arena.cache is None and server.arena.xbuf is None
+
+
 def test_slot_churn_cycles_and_resets_every_row():
     """Admit/close/admit N >> capacity: the FIFO free deque cycles slot
     reuse through EVERY row (the old `list.pop(0)` + append re-issued the
@@ -413,3 +430,167 @@ def test_inactive_slots_do_not_advance():
     for o, n in zip(old_kv, new_kv):
         np.testing.assert_array_equal(np.asarray(o[1]), np.asarray(n[1]))
         assert not np.array_equal(np.asarray(o[0]), np.asarray(n[0]))
+
+
+# ---------------------------------------------------------------------------
+# In-place arena step: parity with the flush-stacked step, write-only rows
+# ---------------------------------------------------------------------------
+
+def _random_arena(params, cfg, rt, capacity, max_len, seed):
+    """An arena of `capacity` rows whose every leaf holds random bits and
+    whose positions are ragged, several past `max_len` (a wrapped ring)."""
+    one = transformer.init_cache(params, cfg, rt, 1, max_len)
+    rng = np.random.RandomState(seed)
+
+    def fill(a):
+        shape = (capacity,) + a.shape
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
+        if a.dtype == jnp.int32:                  # pos
+            return jnp.asarray(rng.randint(0, 3 * max_len, shape), jnp.int32)
+        return jnp.asarray(rng.randn(*shape), a.dtype)
+
+    arena = jax.tree.map(fill, one)
+    for name in ("k_scale", "v_scale"):           # int8 codes' scales
+        if name in arena.get("kv", {}):
+            arena["kv"][name] = jnp.abs(arena["kv"][name]) * 0.01
+    return arena
+
+
+def _leaf(tree, path):
+    for p in path:
+        tree = tree[p.key]
+    return tree
+
+
+ARENA_PARITY = [
+    # (arch, dtype, kv bits, n_layers, cut): dense and moe write in place,
+    # hybrid (mamba state) keeps the masked per-row body
+    ("qwen3-8b", "bfloat16", 16, 3, 0),
+    ("qwen3-8b", "bfloat16", 16, 3, 1),
+    ("qwen3-8b", "bfloat16", 8, 3, 0),
+    ("qwen3-8b", "bfloat16", 8, 3, 1),
+    ("qwen3-8b", "float32", 16, 3, 2),
+    ("granite-moe-1b-a400m", "float32", 16, 2, 1),
+    ("zamba2-7b", "float32", 16, 0, 1),
+]
+
+
+@pytest.mark.parametrize("arch,dtype,bits,n_layers,cut", ARENA_PARITY,
+                         ids=[f"{a}-{d}-kv{b}-cut{c}"
+                              for a, d, b, _, c in ARENA_PARITY])
+def test_arena_step_matches_top_step_and_writes_only_active_rows(
+        arch, dtype, bits, n_layers, cut):
+    """The donated arena step against `make_top_step` (the flush-stacked
+    reference) over ragged positions, a wrapped ring and a random active
+    mask: active rows' tokens and caches are bit-identical to the
+    reference; the only bits that move in an active row are its new
+    entries at (layer >= cut, slot pos % size); inactive rows keep every
+    bit; positions advance on active rows only."""
+    cfg = configs.get(arch, smoke=True)
+    cfg = cfg.with_(dtype=dtype, param_dtype=dtype,
+                    **({"n_layers": n_layers} if n_layers else {}))
+    rt = Runtime(mesh=None, training=False, kv_cache_bits=bits)
+    params = transformer.init_model(jax.random.key(0), cfg)
+    cap, max_len = 6, 8
+    arena = _random_arena(params, cfg, rt, cap, max_len, seed=cut + bits)
+    rng = np.random.RandomState(7 + cut)
+    active = rng.rand(cap) < 0.5
+    active[:2] = [True, False]
+    xbuf = jnp.asarray(rng.randn(cap + 1, 1, 1, cfg.d_model), cfg.adtype())
+    old = jax.tree.map(np.asarray, arena)
+
+    ref_tok, ref = jax.jit(steps.make_top_step(cfg, rt, cut))(
+        params, xbuf[:cap], arena)
+    ref = jax.tree.map(np.asarray, ref)
+    step = jax.jit(steps.make_arena_top_step(cfg, rt, cut),
+                   donate_argnums=(2,))
+    tok, new = step(params, xbuf, arena, jnp.asarray(active))
+    new = jax.tree.map(np.asarray, new)
+
+    np.testing.assert_array_equal(np.asarray(tok)[active],
+                                  np.asarray(ref_tok)[active])
+    np.testing.assert_array_equal(new["pos"],
+                                  np.where(active, old["pos"] + 1,
+                                           old["pos"]))
+    for path, n in jax.tree_util.tree_leaves_with_path(new):
+        o = _leaf(old, path)
+        r = _leaf(ref, path)
+        np.testing.assert_array_equal(n[active], r[active], err_msg=str(path))
+        np.testing.assert_array_equal(n[~active], o[~active],
+                                      err_msg=str(path))
+    if "kv" in new and set(new) == {"pos", "kv"}:
+        # an active row moves only at its written position of each top layer
+        size = new["kv"]["k"].shape[4]
+        for name, n in new["kv"].items():
+            written = np.zeros(n.shape[:5], bool)
+            for row in np.flatnonzero(active):
+                written[row, cut:, 0, :, old["pos"][row] % size] = True
+            o = old["kv"][name]
+            np.testing.assert_array_equal(n[~written], o[~written],
+                                          err_msg=name)
+            assert not np.array_equal(n[written], o[written]), name
+
+
+#: ops that would touch a whole arena leaf: a re-select, a layout or
+#: scan-buffer copy, a transpose, a zero-filled output, a layer merge
+WHOLE_LEAF_OPS = ("select", "copy", "transpose", "broadcast", "concatenate")
+
+
+def _whole_leaf_ops(hlo: str, n_elems: int):
+    """(op, shape) of every instruction in `hlo` (fused computations
+    included) whose output has exactly `n_elems` elements."""
+    found = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", hlo):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        if m.group(2) in WHOLE_LEAF_OPS and int(np.prod(dims)) == n_elems:
+            found.append((m.group(2), m.group(1)))
+    return found
+
+
+def _unaliased_cache_params(hlo: str):
+    """Entry parameters holding a cache leaf that the executable does not
+    alias to an output (`input_output_alias`)."""
+    aliased = {int(a) for a in re.findall(
+        r"\{[\d,]*\}: \((\d+), \{", hlo.split("\n", 1)[0])}
+    params = re.findall(
+        r"parameter\((\d+)\).*op_name=\"cache\[([^\"]*)\"", hlo)
+    assert params, "no cache parameter in the program"
+    return [name for num, name in params if int(num) not in aliased]
+
+
+@pytest.mark.parametrize("bits", [16, 8])
+def test_arena_step_programs_touch_no_whole_leaf(bits):
+    """The served step programs, as the server jits them (`fused_step`,
+    `arena_step`, cache donated), hold no select, copy, transpose,
+    broadcast or concatenate the size of an arena KV leaf, and alias
+    every cache leaf in place: only the rows' new entries are written."""
+    from repro.runtime.server import jit_serving_steps
+
+    cfg = _smoke_cfg(compressor="randtopk", k=8).with_(n_layers=3)
+    rt = Runtime(mesh=None, training=False, kv_cache_bits=bits)
+    params = transformer.init_model(jax.random.key(0), cfg)
+    cap, max_len, rows = 5, 7, 4
+    cache = jax.tree.map(lambda a: jnp.stack([a] * cap),
+                         transformer.init_cache(params, cfg, rt, 1, max_len))
+    leaf = cache["kv"]["k"]
+    n_elems = int(np.prod(leaf.shape))
+    others = [a for a in jax.tree.leaves(params) if a.size == n_elems]
+    assert not others, "the leaf's element count must be unique"
+    top, fused = jit_serving_steps(steps.make_arena_top_step(cfg, rt, 1),
+                                   dtype=jnp.float32)
+    xbuf = jnp.zeros((cap + 1, 1, 1, cfg.d_model), jnp.float32)
+    active = jnp.ones((cap,), bool)
+    x = jnp.asarray(np.random.RandomState(0).randn(
+        rows, 1, 1, cfg.d_model).astype(np.float32))
+    payload = _wire_payload(C.make_compressor("randtopk", k=8), x)
+    slots = jnp.arange(rows, dtype=jnp.int32)
+    programs = {
+        "arena_step": top.lower(params, xbuf, cache, active),
+        "fused_step": fused.lower(params, xbuf, payload, slots, cache,
+                                  active)}
+    for name, lowered in programs.items():
+        hlo = lowered.compile().as_text()
+        assert f"jit_{name}" in hlo.split("\n", 1)[0]
+        assert _whole_leaf_ops(hlo, n_elems) == [], name
+        assert _unaliased_cache_params(hlo) == [], name

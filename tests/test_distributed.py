@@ -143,7 +143,7 @@ def test_sliding_window_masks_old_positions():
     logits, _ = transformer.forward(params, cfg, rt, batch)
     # decode with a window-sized rolling cache reproduces the same logits
     cache = transformer.init_cache(params, cfg, rt, 1, 12)
-    assert cache["kv"]["k"].shape[2] == 4  # rolling buffer == window
+    assert cache["kv"]["k"].shape[3] == 4  # rolling buffer == window
     outs = []
     for i in range(12):
         lg, cache = transformer.decode_step(params, cfg, rt,

@@ -88,7 +88,10 @@ def test_sharded_step_matches_unsharded_and_freezes_inactive():
     """Direct step drive on 8 forced devices: the shard_map arena step's
     tokens are bit-identical to the mesh-less step and every new-cache
     leaf agrees within a tolerance, at data-only, data x model, and pod
-    meshes — and inactive rows never move (bit-identical).
+    meshes — and inactive rows never move (bit-identical). The arena
+    holds random entries at ragged positions, several past the ring's
+    size, so each shard writes its rows' new K/V in place at different
+    slots: an active row moves only there, in the layers above the cut.
 
     Why a tolerance on the KV leaves: each new K/V element is a
     d_model-term f32 dot product, and a shard runs the same per-row
@@ -101,9 +104,18 @@ def test_sharded_step_matches_unsharded_and_freezes_inactive():
         rt = Runtime(mesh=None, training=False)
         cap = 8
         ref_step = jax.jit(steps.make_arena_top_step(cfg, rt, 1))
+        rng = np.random.RandomState(3)
         cache0 = jax.tree.map(
-            lambda a: jnp.stack([a] * cap),
+            lambda a: jnp.asarray(rng.randint(0, 24, (cap,) + a.shape)
+                                  if a.dtype == jnp.int32 else
+                                  rng.randn(cap, *a.shape), a.dtype),
             transformer.init_cache(params, cfg, rt, 1, 8))
+        pos0 = np.asarray(cache0["pos"])
+        assert pos0.max() >= 8                     # a wrapped ring
+        size = cache0["kv"]["k"].shape[4]
+        written = np.zeros(cache0["kv"]["k"].shape[:5], bool)
+        for row in range(0, cap, 2):
+            written[row, 1:, 0, :, pos0[row] % size] = True
         xbuf = jnp.asarray(np.random.RandomState(0).randn(
             cap + 1, 1, 1, cfg.d_model).astype(np.float32))
         active = jnp.asarray([True, False] * (cap // 2))
@@ -133,6 +145,11 @@ def test_sharded_step_matches_unsharded_and_freezes_inactive():
             for o, n in zip(jax.tree.leaves(cache0), jax.tree.leaves(new)):
                 np.testing.assert_array_equal(np.asarray(o)[1::2],
                                               np.asarray(n)[1::2])
+            # active rows: only their new entries moved
+            for name in ("k", "v"):
+                o = np.asarray(cache0["kv"][name])
+                n = np.asarray(new["kv"][name])
+                np.testing.assert_array_equal(n[~written], o[~written])
             print("mesh", dict(mesh.shape), "ok")
     """)
     assert out.count("ok") == 3

@@ -6,6 +6,7 @@ factors — plus the closed-form serving-kernel cost predictions in
 import numpy as np
 
 import repro.configs as configs
+from repro.models import attention
 from repro.roofline import analysis, hlo
 
 
@@ -236,7 +237,8 @@ def test_serving_step_costs_scaling():
     state = 12_345
     flops, floor = analysis.serving_step_costs(cfg, 1, 8, 20, state)
     assert floor == 2.0 * state
-    score = 2 * cfg.n_heads * cfg.hd * 20
+    # the attention dots run over the cache's lane-padded head dim
+    score = 2 * cfg.n_heads * attention.kv_width(cfg) * 20
     assert flops == 2.0 * 8 * (analysis.top_matmul_params(cfg, 1) + score)
     # flops scale linearly in arena capacity; byte floor does not move
     flops2, floor2 = analysis.serving_step_costs(cfg, 1, 16, 20, state)

@@ -1,6 +1,7 @@
 """Compile-only checks: every Pallas kernel the served path reaches on a
 TPU lowers under Mosaic and compiles for a described v5e chip at the
-published width of the benchmark model (d = 4096, k = 64, a bf16 arena).
+published width of the benchmark model (d = 4096, k = 64, a bf16 arena),
+and the served fused step updates its arena in place there.
 
 Nothing runs: the chip is described (`jax.experimental.topologies`), not
 attached, so these tests catch what interpret mode cannot — unaligned
@@ -9,14 +10,22 @@ is built inside a module fixture, never at import: only one process at a
 time may load the TPU library, and building it while pytest collects
 would give each xdist worker a different set of tests.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.payload import Payload, PayloadMeta
 from repro.kernels.decode import kernel as dec
 from repro.kernels.encode import kernel as enc
 from repro.kernels.randtopk import kernel as sel
+from repro.models import transformer
+from repro.models.config import ArchConfig, Runtime
+from repro.runtime import steps
+from repro.runtime.server import jit_serving_steps
 
 D, K = 4096, 64
 ROWS = 8            # a full flush bucket; one client step encodes 1 row
@@ -100,3 +109,64 @@ def test_decode_to_slots_compiles(one_chip, kind):
              one_chip, ((CAPACITY + 1, D), jnp.bfloat16),
              ((ROWS,), jnp.int32),
              *[((ROWS,) + s, dt) for s, dt in LEAVES[kind]])
+
+
+#: (d_model, heads, kv heads, head dim, d_ff, arena slots): Qwen3-8B and
+#: Phi-3-mini widths and arenas, four layers (the chat cell's label owner)
+#: and a small vocabulary; a KV leaf (1.07 GB, 537 MB) is larger than the
+#: chip's VMEM, so it stays in HBM as served
+STEP_SHAPES = {"qwen3-8b": (4096, 32, 8, 128, 12288, 128),
+               "phi3-mini": (3072, 32, 32, 96, 8192, 16)}
+
+
+@pytest.mark.parametrize("shape", sorted(STEP_SHAPES))
+def test_fused_arena_step_updates_in_place(one_chip, shape):
+    """The served fused decode+step program at Qwen3-8B (GQA, head dim
+    128) and Phi-3-mini (MHA, head dim 96) widths, cut to four layers,
+    with a bf16 arena of 1024 positions, compiled for the chip: the
+    donated KV leaves alias in place, no op copies or re-selects a whole
+    leaf, and the program's temporaries stay under one K leaf (the step
+    used to copy the arena several times over)."""
+    d, heads, kv_heads, head_dim, d_ff, cap = STEP_SHAPES[shape]
+    cfg = ArchConfig(name=shape, family="dense", n_layers=4, d_model=d,
+                     n_heads=heads, n_kv_heads=kv_heads, head_dim=head_dim,
+                     d_ff=d_ff, vocab=2048, qk_norm=True,
+                     param_dtype="bfloat16", dtype="bfloat16")
+    rt = Runtime(mesh=None, training=False)
+    max_len, rows = 1024, 8
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda k: transformer.init_model(k, cfg), jax.random.key(0)))
+    cache = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((cap,) + a.shape, a.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: transformer.init_cache(None, cfg, rt, 1,
+                                                      max_len)))
+    payload = Payload(meta=PayloadMeta("sparse", d=cfg.d_model, k=K),
+                      values=spec(jax.ShapeDtypeStruct((rows, 1, 1, K),
+                                                       jnp.float32)),
+                      indices=spec(jax.ShapeDtypeStruct((rows, 1, 1, K),
+                                                        jnp.uint16)))
+    _, fused = jit_serving_steps(steps.make_arena_top_step(cfg, rt, 0),
+                                 dtype=cfg.adtype(), backend="xla")
+    compiled = fused.lower(
+        params, spec(jax.ShapeDtypeStruct((cap + 1, 1, 1, cfg.d_model),
+                                          cfg.adtype())),
+        payload, spec(jax.ShapeDtypeStruct((rows,), jnp.int32)), cache,
+        spec(jax.ShapeDtypeStruct((cap,), jnp.bool_))).compile()
+    leaf = cache["kv"]["k"]
+    leaf_bytes = int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+    hlo = compiled.as_text()
+    aliased = re.findall(r"\{[\d,]*\}: \((\d+), \{", hlo.split("\n", 1)[0])
+    kv_params = re.findall(
+        r"parameter\((\d+)\).*op_name=\"cache\[\\?'kv\\?'\]", hlo)
+    assert kv_params and set(kv_params) <= set(aliased)
+    whole = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]*)\]\S* (copy|transpose|select)\(", hlo)
+        if int(np.prod([int(d) for d in m.group(1).split(",") if d])) ==
+        int(np.prod(leaf.shape))]
+    assert whole == []
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
